@@ -44,15 +44,18 @@ ARCH_IDS: List[str] = list(_MODULES)
 
 
 def get_config(arch: str) -> ModelConfig:
-    if arch not in _MODULES:
-        raise KeyError(f"unknown arch {arch!r}; known: {ARCH_IDS}")
-    return _MODULES[arch].CONFIG
+    """The config named ``arch``: an arch id gives the published widths,
+    ``<arch>-smoke`` that arch's smoke-scale config."""
+    base = arch[:-len("-smoke")] if arch.endswith("-smoke") else arch
+    if base not in _MODULES:
+        raise KeyError(f"unknown arch {arch!r}; known: {ARCH_IDS} "
+                       f"(each also as '<arch>-smoke')")
+    mod = _MODULES[base]
+    return mod.SMOKE_CONFIG if base != arch else mod.CONFIG
 
 
 def get_smoke_config(arch: str) -> ModelConfig:
-    if arch not in _MODULES:
-        raise KeyError(f"unknown arch {arch!r}; known: {ARCH_IDS}")
-    return _MODULES[arch].SMOKE_CONFIG
+    return get_config(f"{arch}-smoke")
 
 
 def get_shape(name: str) -> ShapeConfig:

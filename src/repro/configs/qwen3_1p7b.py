@@ -1,4 +1,4 @@
-"""qwen3-1.7b — dense, GQA, qk_norm. [hf:Qwen/Qwen3-8B; hf]"""
+"""qwen3-1.7b — dense, GQA, qk_norm. [hf:Qwen/Qwen3-1.7B config.json]"""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -16,7 +16,7 @@ CONFIG = ModelConfig(
     qkv_bias=False,
     rope_theta=1_000_000.0,
     tie_embeddings=True,
-    source="hf:Qwen/Qwen3-8B; hf",
+    source="hf:Qwen/Qwen3-1.7B config.json",
 )
 
 SMOKE_CONFIG = CONFIG.replace(

@@ -1,5 +1,5 @@
 """Real JAX executors: token-by-token execution of scheduler-issued batches on
-an actual model (smoke-scale on CPU; the same code path drives a TPU slice).
+an actual model (smoke-scale configs on CPU, published widths on a TPU).
 
 Two KV backends behind one engine-facing contract (``dispatch`` / ``wait`` —
 with ``execute`` as the serial composition — plus ``release_request`` /
@@ -12,12 +12,12 @@ slots one request at a time with bucketed padding, decode runs one
 the paged backend is pinned against.
 
 ``PagedRealExecutor`` — block-paged KV owned by ``BlockManager``: a single
-``[num_blocks, block_size, heads, dim]`` K/V pool per layer, per-request
+``[num_blocks, heads, block_size, dim]`` K/V pool per layer, per-request
 block tables, batched multi-request prefill (shape-bucketed on batch and
-length to bound recompilation, optionally through the Pallas
-``flash_prefill`` kernel) and decode through the Pallas ``paged_attention``
-kernel — falling back to ``kernels/ref.py`` on CPU so CI exercises the same
-path. Prefix-sharing chains map to physically shared (ref-counted) blocks
+length to bound recompilation, through the Pallas ``flash_prefill`` kernel
+on an accelerator) and decode through the Pallas ``paged_attention`` kernel
+on an accelerator, or the gathered-page jnp recipe on CPU (the tests'
+path). Prefix-sharing chains map to physically shared (ref-counted) blocks
 with copy-on-write on divergence; preemption releases real blocks instead of
 whole slots, so the scheduler's token ledger and device residency agree.
 
@@ -107,6 +107,9 @@ class _ExecutorBase:
         # compile seconds spent pre-staging shape buckets during another
         # batch's device compute (never charged to any batch duration)
         self.prestage_compile_s = 0.0
+        # every ahead-of-time compile this executor ran, and their seconds
+        self.compile_count = 0
+        self.compile_s_total = 0.0
 
     # ------------------------------------------------------------- admission
     def validate_relquery(self, rq: RelQuery) -> None:
@@ -135,7 +138,10 @@ class _ExecutorBase:
         clock and Fig. 7's α/β fit)."""
         t0 = _time.perf_counter()
         exe = fn.lower(*args).compile()
-        return exe, _time.perf_counter() - t0
+        dt = _time.perf_counter() - t0
+        self.compile_count += 1
+        self.compile_s_total += dt
+        return exe, dt
 
     def _sample(self, logits) -> np.ndarray:
         return np.asarray(jnp.argmax(logits, axis=-1))
@@ -180,6 +186,10 @@ class RealExecutor(_ExecutorBase):
         self._prefill_fn = {}
         self._decode_fn = None
         self._decode_jit = jax.jit(model.decode_step, donate_argnums=(1,))
+        # slot writes run jitted on the donated cache, in place: an eager
+        # ``.at[].set`` would hold a second copy of the whole cache in HBM
+        self._write_fn = {}
+        self._put_fn = None
         self._compile_s = 0.0     # compile time to subtract from this batch
         # host KV tier: req_id -> (request, slot position, stashed cache
         # slice). Slices start as device arrays with an async device->host
@@ -288,16 +298,22 @@ class RealExecutor(_ExecutorBase):
         if staged is not None:
             stash = staged
         i = self._alloc_slot(req)
-
-        def put(dst, src):
-            if isinstance(src, str):
-                return dst
-            ax = self._slot_axis(dst)
-            idx = [slice(None)] * dst.ndim
-            idx[ax] = slice(i, i + 1)
-            return dst.at[tuple(idx)].set(jnp.asarray(src).astype(dst.dtype))
-
-        self.cache = jax.tree.map(put, self.cache, stash)
+        # the "skip" sentinels of slot-less leaves become None (no argument)
+        src = jax.tree.map(
+            lambda x: None if isinstance(x, str) else jnp.asarray(x), stash)
+        args = (self.cache, src, jnp.asarray(i, jnp.int32))
+        if self._put_fn is None:
+            def put(cache, src, slot):
+                return jax.tree.map(
+                    lambda dst, x: dst if x is None else
+                    jax.lax.dynamic_update_slice_in_dim(
+                        dst, x.astype(dst.dtype), slot,
+                        axis=self._slot_axis(dst)),
+                    cache, src)
+            self._put_fn, dt = self._aot(jax.jit(put, donate_argnums=(0,)),
+                                         *args)
+            self._compile_s += dt
+        self.cache = self._put_fn(*args)
         self.slots[i].position = position
         return 0.0
 
@@ -364,23 +380,32 @@ class RealExecutor(_ExecutorBase):
             self.prestage_compile_s += dt
 
     def _write_slot_cache(self, slot: int, kv) -> None:
-        """Copy a single-sequence prefill cache into slot ``slot``."""
-        def write(dst, src):
+        """Copy a single-sequence prefill cache into slot ``slot``, in place
+        on the donated cache (one compile per prefill cache shape)."""
+        def write(dst, src, slot):
             if dst.ndim == src.ndim and dst.shape == src.shape:
                 return src  # scalar-like entries (not per-slot)
             # batch dim location differs per model family; find the axis where
             # dst has max_slots and src has 1
             for ax in range(dst.ndim):
                 if dst.shape[ax] == self.max_slots and src.shape[ax] == 1:
-                    idx = [slice(None)] * dst.ndim
-                    idx[ax] = slice(slot, slot + 1)
                     pad = [(0, d - s) if a != ax else (0, 0)
                            for a, (d, s) in enumerate(zip(dst.shape, src.shape))]
                     if any(p != (0, 0) for p in pad):
                         src = jnp.pad(src, pad)
-                    return dst.at[tuple(idx)].set(src.astype(dst.dtype))
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        dst, src.astype(dst.dtype), slot, axis=ax)
             raise ValueError(f"cannot place prefill cache {src.shape} into {dst.shape}")
-        self.cache = jax.tree.map(write, self.cache, kv)
+
+        args = (self.cache, kv, jnp.asarray(slot, jnp.int32))
+        key = tuple(x.shape for x in jax.tree.leaves(kv))
+        if key not in self._write_fn:
+            fn = jax.jit(lambda cache, kv, slot: jax.tree.map(
+                lambda d, s: write(d, s, slot), cache, kv),
+                donate_argnums=(0,))
+            self._write_fn[key], dt = self._aot(fn, *args)
+            self._compile_s += dt
+        self.cache = self._write_fn[key](*args)
 
     # ------------------------------------------------------------------ decode
     def _decode_issue(self, reqs: List[Request]) -> object:
@@ -531,9 +556,10 @@ class PagedRealExecutor(_ExecutorBase):
             model = model.with_prefill_attn("flash")
         super().__init__(model, params, max_len=max_len,
                          prefix_cache=prefix_cache, greedy=greedy)
-        # Pallas on a real accelerator, pure-jnp reference on CPU (CI's
-        # fallback); 'pallas-interpret' forces the kernel through the
-        # interpreter for parity debugging.
+        # Pallas on a real accelerator; on CPU (the tests' path) the jnp
+        # recipe that keeps paged and dense streams bit-identical;
+        # 'pallas-interpret' forces the kernel through the interpreter for
+        # parity debugging.
         self.attn_impl = attn_impl or ("ref" if on_cpu else "pallas")
         self.block_size = block_size
         self.num_blocks = num_blocks

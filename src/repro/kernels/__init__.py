@@ -1,3 +1,4 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas TPU kernels of the serving hot path (paged_attention, flash_prefill)
+# plus rwkv6_chunk; ref.py holds the pure-jnp oracle of each. Every kernel
+# takes a required ``interpret`` flag: False compiles the Mosaic kernel,
+# True runs its body in the Pallas interpreter (CPU validation).

@@ -74,7 +74,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
                                              "q_block", "kv_block", "interpret"))
 def flash_prefill(q, k, v, *, causal: bool = True, window: int = 0,
                   q_offset: int = 0, q_block: int = 128, kv_block: int = 128,
-                  interpret: bool = True):
+                  interpret: bool):
+    """See module docstring for layouts. ``interpret=True`` runs the kernel
+    body through the Pallas interpreter (CPU validation); there is no
+    default, so no caller lands in the interpreter by omission."""
     B, G, S, R, hd = q.shape
     T = k.shape[2]
     q_block = min(q_block, S)

@@ -4,12 +4,14 @@ One query token per sequence attends a paged KV cache. TPU adaptation of
 vLLM's CUDA kernel: the GPU's shared-memory staging becomes explicit HBM→VMEM
 BlockSpec tiling; the block table is scalar-prefetched (SMEM) and drives the
 page index_map, so each grid step DMAs exactly one [page_size, head_dim] K/V
-tile per kv head — MXU-aligned when head_dim is a multiple of 128 and
-page_size a multiple of 8.
+tile of one kv head. The pool keeps the kv-head axis ahead of the page axis
+so that tile is the block's last two dims — Mosaic requires those to be
+(8, 128)-divisible or whole, which a [page, KV, hd] layout with one head per
+step cannot satisfy.
 
 Layouts (matching the engine's packed-GQA scheme):
   q            [B, KV, Qp, hd]     one token per sequence
-  k/v_pages    [P, page, KV, hd]   paged KV pool
+  k/v_pages    [P, KV, page, hd]   paged KV pool
   block_tables [B, max_pages]      page ids per sequence (pad with 0)
   context_lens [B]                 valid tokens per sequence
 """
@@ -49,8 +51,8 @@ def _kernel(bt_ref, cl_ref,           # scalar-prefetch refs
         hd = q_ref.shape[-1]
         scale = 1.0 / math.sqrt(hd)
         q = q_ref[0, 0].astype(jnp.float32) * scale       # [Qt*Qp, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)            # [page, hd]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)               # [page, hd]
+        v = v_ref[0, 0].astype(jnp.float32)
         rows = q.shape[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))   # [rows, page]
         tok = page_start + jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 1)
@@ -76,8 +78,10 @@ def _kernel(bt_ref, cl_ref,           # scalar-prefetch refs
 
 @functools.partial(jax.jit, static_argnames=("interpret", "num_q_tokens"))
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                    *, interpret: bool = True, num_q_tokens: int = 1):
-    """See module docstring for layouts. interpret=True validates on CPU.
+                    *, interpret: bool, num_q_tokens: int = 1):
+    """See module docstring for layouts. ``interpret=True`` runs the kernel
+    body through the Pallas interpreter (CPU validation); there is no
+    default, so no caller lands in the interpreter by omission.
 
     ``num_q_tokens`` > 1 runs a *chunk* of query tokens per sequence against
     the paged cache (speculative verify / chunked-prefill continuation): the
@@ -88,25 +92,24 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     if rows % num_q_tokens:
         raise ValueError(f"q rows {rows} not divisible by num_q_tokens"
                          f" {num_q_tokens}")
-    Qp = rows
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     max_pages = block_tables.shape[1]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, KV, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, Qp, hd), lambda b, h, i, bt, cl: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, i, bt, cl: (bt[b, i], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, i, bt, cl: (bt[b, i], 0, h, 0)),
+            pl.BlockSpec((1, 1, rows, hd), lambda b, h, i, bt, cl: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, h, i, bt, cl: (bt[b, i], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, h, i, bt, cl: (bt[b, i], h, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, Qp, hd), lambda b, h, i, bt, cl: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, rows, hd), lambda b, h, i, bt, cl: (b, h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Qp, hd), jnp.float32),
-            pltpu.VMEM((Qp, 1), jnp.float32),
-            pltpu.VMEM((Qp, 1), jnp.float32),
+            pltpu.VMEM((rows, hd), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(_kernel, page_size=page_size, num_pages=max_pages,

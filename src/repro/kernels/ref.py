@@ -10,19 +10,20 @@ import jax.numpy as jnp
 
 def paged_attention_ref(q, k_pages, v_pages, block_tables, context_lens,
                         *, num_q_tokens: int = 1):
-    """q: [B, KV, Qt*Qp, hd]; k/v_pages: [num_pages, page, KV, hd];
+    """q: [B, KV, Qt*Qp, hd]; k/v_pages: [num_pages, KV, page, hd];
     block_tables: [B, max_pages]; context_lens: [B] -> out [B, KV, Qt*Qp, hd].
 
     ``num_q_tokens`` (Qt) > 1: a chunk of query tokens per sequence, token t
     at absolute position ``context_lens[b] - Qt + t`` (causally masked) —
     mirrors the Pallas kernel's chunk mode."""
     B, KV, rows, hd = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     max_pages = block_tables.shape[1]
     scale = 1.0 / math.sqrt(hd)
 
-    k = k_pages[block_tables]          # [B, max_pages, page, KV, hd]
-    v = v_pages[block_tables]
+    # [B, max_pages, KV, page, hd] -> [B, max_pages * page, KV, hd]
+    k = jnp.swapaxes(k_pages[block_tables], 2, 3)
+    v = jnp.swapaxes(v_pages[block_tables], 2, 3)
     k = k.reshape(B, max_pages * page, KV, hd)
     v = v.reshape(B, max_pages * page, KV, hd)
     s = jnp.einsum("bgqh,btgh->bgqt", q.astype(jnp.float32) * scale,

@@ -58,7 +58,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s_ref, o_ref, s_out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def rwkv6_chunk(r, k, v, logw, u, state, *, interpret: bool = True):
+def rwkv6_chunk(r, k, v, logw, u, state, *, interpret: bool):
     B, c, H, K = r.shape
     V = state.shape[-1]
     grid = (B, H)

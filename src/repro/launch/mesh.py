@@ -4,53 +4,16 @@ A FUNCTION, not a module-level constant — importing this module never touches
 jax device state. Single pod: 16x16 = 256 chips (data x model). Multi-pod:
 2x16x16 = 512 chips (pod x data x model); the pod axis is pure DP for serving
 and the outer gradient-reduction tier for training. Scaling to more pods is a
-mesh-shape change only.
-
-``compat_make_mesh`` is the one mesh constructor everything (production
-meshes, the subprocess sharding tests) routes through: ``jax.sharding.
-AxisType`` only exists from jax 0.5; on older installs (e.g. the 0.4.x in
-this image) ``axis_types`` must simply not be passed — the default is Auto
-either way.
+mesh-shape change only. Every axis is ``AxisType.Auto``: shardings come from
+the params' specs and XLA propagation.
 """
 from __future__ import annotations
 
-from typing import Sequence, Tuple
-
 import jax
-
-
-def supports_axis_types() -> bool:
-    """Whether the installed jax has ``jax.sharding.AxisType`` (>= 0.5)."""
-    return hasattr(jax.sharding, "AxisType")
-
-
-def compat_make_mesh(shape: Sequence[int], axis_names: Tuple[str, ...]):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported, and
-    the (equivalent) implicit default where ``AxisType`` does not exist."""
-    if supports_axis_types():
-        return jax.make_mesh(
-            tuple(shape), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(tuple(shape), tuple(axis_names))
-
-
-def compat_set_mesh(mesh) -> None:
-    """Install ``mesh`` as the ambient mesh. ``jax.set_mesh`` only exists on
-    newer jax; on 0.4.x the equivalent is entering the mesh's resource-env
-    context (which ``Mesh`` exposes as a context manager) for the rest of the
-    process — the idiom the subprocess sharding tests rely on."""
-    if hasattr(jax, "set_mesh"):
-        jax.set_mesh(mesh)
-    else:
-        mesh.__enter__()
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
-
-
-def make_host_mesh():
-    """Degenerate 1-device mesh for smoke-scale runs."""
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -5,8 +5,9 @@ Two execution modes:
                   match the paper's OPT-13B/A100 regime); supports
                   --num-replicas N data-parallel engine replicas behind the
                   relQuery-affine router (repro.serving)
-  (default)       real JAX execution of a smoke-scale model on this host
-                  (single replica — one model fits this machine)
+  (default)       real JAX execution of ``--arch`` on this host (single
+                  replica): an arch id runs its published widths,
+                  ``<arch>-smoke`` the smoke-scale config CPU runs use
 
 and two drive modes:
   (default)       closed-loop trace replay through the Frontend shim
@@ -25,7 +26,7 @@ bit-identical to the unplanned replay.
   PYTHONPATH=src python -m repro.launch.serve --simulate --open-loop
   PYTHONPATH=src python -m repro.launch.serve --simulate --plan full \
       --dup-row-fraction 0.5 --prefix-sharing on
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b --num-relqueries 4
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b-smoke --num-relqueries 4
 """
 from __future__ import annotations
 
@@ -253,7 +254,10 @@ def main() -> None:
                          "copies of earlier rows (duplicate-heavy regime the "
                          "planner's dedup pass targets); 0.0 is byte-"
                          "identical to historical traces")
-    ap.add_argument("--arch", default="qwen3-1.7b")
+    ap.add_argument("--arch", default="qwen3-1.7b",
+                    help="real-mode config name: an arch id serves its "
+                         "published widths, '<arch>-smoke' its smoke-scale "
+                         "config")
     ap.add_argument("--num-relqueries", type=int, default=100)
     ap.add_argument("--rate", type=float, default=1.0)
     ap.add_argument("--max-requests", type=int, default=100)
@@ -529,15 +533,17 @@ def main() -> None:
     else:
         import jax
 
-        from repro.configs import get_smoke_config
+        from repro.configs import get_config
         from repro.engine.tokenizer import HashTokenizer
+        from repro.launch.compile_cache import use_compile_cache
         from repro.models.registry import build_model
         from repro.serving import build_real_engine
 
         if args.num_replicas != 1:
             raise SystemExit("real-JAX mode runs a single replica on this host; "
                              "use --simulate for --num-replicas > 1")
-        cfg = get_smoke_config(args.arch)
+        use_compile_cache()
+        cfg = get_config(args.arch)
         model = build_model(cfg)
         params = model.init_params(jax.random.PRNGKey(args.seed))
         tok = HashTokenizer(vocab_size=cfg.vocab_size - 2)

@@ -168,16 +168,19 @@ class DenseTransformer:
         return self.n_win == 0
 
     def init_paged_pools(self, num_blocks: int, block_size: int):
-        """Block-paged KV pools: one ``[num_blocks, block_size, KVs, hd]``
+        """Block-paged KV pools: one ``[num_blocks, KVs, block_size, hd]``
         K and V pool per layer, stacked over (group, layer-in-group) so the
-        whole cache is two arrays. Block id ``num_blocks - 1`` is conventionally
-        the executor's scratch block (pad rows / pad table entries)."""
+        whole cache is two arrays. The kv-head axis sits ahead of the page
+        axis so one head's page is a contiguous ``[block_size, hd]`` tile —
+        the block ``paged_attention`` DMAs per grid step. Block id
+        ``num_blocks - 1`` is conventionally the executor's scratch block
+        (pad rows / pad table entries)."""
         if not self.supports_paged():
             raise NotImplementedError(
                 f"{self.cfg.name}: paged KV supports full-attention archs only "
                 f"(this arch has {self.n_win} window layer(s) per group)")
-        shp = (self.n_groups, self.n_full, num_blocks, block_size,
-               self.layout.kv_slots, self.cfg.head_dim)
+        shp = (self.n_groups, self.n_full, num_blocks, self.layout.kv_slots,
+               block_size, self.cfg.head_dim)
         return {"k": jnp.zeros(shp, self._dtype),
                 "v": jnp.zeros(shp, self._dtype)}
 
@@ -187,11 +190,11 @@ class DenseTransformer:
         k/v_full ``[G, n_full, B, L, KVs, hd]`` (L a multiple of block_size);
         ``block_tables`` ``[B, L // block_size]`` routes each block — pad rows
         and pad blocks should point at the scratch block."""
-        bs = pools["k"].shape[3]
+        bs = pools["k"].shape[4]
         for name in ("k", "v"):
             c = caches[f"{name}_full"]
             G, NF, B, L, KVs, hd = c.shape
-            c = c.reshape(G, NF, B, L // bs, bs, KVs, hd)
+            c = c.reshape(G, NF, B, L // bs, bs, KVs, hd).swapaxes(4, 5)
             pools[name] = pools[name].at[:, :, block_tables].set(
                 c.astype(pools[name].dtype))
         return pools
@@ -206,13 +209,13 @@ class DenseTransformer:
         scatters the new token's K/V into its pool at (block_tables[b,
         pos // bs], pos % bs) then attends through ``paged_attention``
         (``attn_impl='pallas'``/'pallas-interpret') or the pure-jnp reference
-        (``'ref'`` — the CPU fallback CI exercises). Returns (logits, pools);
+        (``'ref'`` — the CPU path the tests run). Returns (logits, pools);
         pools should be donated by the jit wrapper.
         """
         from repro.kernels.paged_attention import paged_attention
 
         cfg = self.cfg
-        bs = pools["k"].shape[3]
+        bs = pools["k"].shape[4]
         B = tokens.shape[0]
         x = self.embed_tokens(params, tokens)
         pools = dict(pools)
@@ -225,20 +228,20 @@ class DenseTransformer:
                 h = L.rmsnorm(x, pp["ln1"][p], cfg.norm_eps)
                 q, k, v = self._qkv(pp, p, h, positions, "global")
                 i = self.full_idx[p]
-                pools["k"] = pools["k"].at[g, i, bids, offs].set(
+                pools["k"] = pools["k"].at[g, i, bids, :, offs].set(
                     k.astype(pools["k"].dtype))
-                pools["v"] = pools["v"].at[g, i, bids, offs].set(
+                pools["v"] = pools["v"].at[g, i, bids, :, offs].set(
                     v.astype(pools["v"].dtype))
                 if attn_impl == "ref":
-                    # CPU fallback: gather the sequence's pages into a dense
+                    # CPU path: gather the sequence's pages into a dense
                     # [B, T, G, hd] view and run the *exact* dense decode
                     # recipe (same dtype roundings, same masking) — paged and
                     # dense backends then emit bit-identical tokens even in
                     # bf16, while T stays the bucketed block span instead of
                     # max_len.
-                    kg = pools["k"][g, i][block_tables]   # [B, NB, bs, KVs, hd]
-                    vg = pools["v"][g, i][block_tables]
-                    Bq, NB, bsz, KVs, hd = kg.shape
+                    kg = pools["k"][g, i][block_tables].swapaxes(2, 3)
+                    vg = pools["v"][g, i][block_tables].swapaxes(2, 3)
+                    Bq, NB, bsz, KVs, hd = kg.shape       # [B, NB, bs, KVs, hd]
                     o = L.decode_attention(
                         q, kg.reshape(Bq, NB * bsz, KVs, hd),
                         vg.reshape(Bq, NB * bsz, KVs, hd), positions)

@@ -81,6 +81,9 @@ def build_real_engine(arch: str = "qwen3-1.7b", scheduler: str = "relserve",
                       debug_invariants: bool = False, **executor_kw):
     """A single-replica real-JAX serving engine on the chosen KV backend.
 
+    ``arch`` names the config (``get_config``): an arch id serves its
+    published widths, ``'<arch>-smoke'`` the smoke-scale config the CPU
+    tests run; weights are random from ``seed`` unless ``params`` is given.
     ``kv_backend='dense'`` is the per-slot baseline; ``'paged'`` runs the
     block-paged executor (BlockManager pools + paged-attention decode), with
     physically shared prefix blocks whenever the scheduler runs with
@@ -89,14 +92,14 @@ def build_real_engine(arch: str = "qwen3-1.7b", scheduler: str = "relserve",
     """
     import jax
 
-    from repro.configs import get_smoke_config
+    from repro.configs import get_config
     from repro.engine.engine import ServingEngine
     from repro.engine.executor import make_real_executor
 
     from repro.models.registry import build_model
 
     if model is None:
-        model = build_model(get_smoke_config(arch))
+        model = build_model(get_config(arch))
     if params is None:
         params = model.init_params(jax.random.PRNGKey(seed))
     pc = PrefixCache(block_size=block_size)

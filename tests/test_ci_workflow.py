@@ -39,6 +39,7 @@ FULL_JOB_ONLY = {
     "test_models_smoke.py",         # every registry arch compiles + runs
     "test_roofline_accounting.py",  # flop/byte accounting on real models
     "test_training.py",             # training-loop smoke
+    "test_tpu_compile.py",          # kernels compiled for a described v5e
 }
 
 

@@ -19,9 +19,8 @@ import jax
 from repro.configs import get_smoke_config
 from repro.launch.cells import build_cell, lower_cell
 from repro.launch.hlo_stats import collective_stats, dot_flops
-from repro.launch.mesh import compat_make_mesh
-
-mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 out = {{}}
 for arch in {archs!r}:
     cfg = get_smoke_config(arch)

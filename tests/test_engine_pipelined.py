@@ -400,7 +400,7 @@ def _real_streams_and_events(backend, loop):
 
     model, params, trace = _real_fixture()
     trace = copy.deepcopy(trace)
-    engine = build_real_engine("qwen3-1.7b", "relserve", backend,
+    engine = build_real_engine("qwen3-1.7b-smoke", "relserve", backend,
                                limits=BatchLimits(cap=100_000), max_len=512,
                                model=model, params=params, engine_loop=loop)
     rep = engine.run_trace(trace)

@@ -24,8 +24,8 @@ def test_paged_attention(B, KV, Qp, hd, page, maxp, dtype):
     P = B * maxp + 2
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, KV, Qp, hd)).astype(dtype)
-    kp = jax.random.normal(ks[1], (P, page, KV, hd)).astype(dtype)
-    vp = jax.random.normal(ks[2], (P, page, KV, hd)).astype(dtype)
+    kp = jax.random.normal(ks[1], (P, KV, page, hd)).astype(dtype)
+    vp = jax.random.normal(ks[2], (P, KV, page, hd)).astype(dtype)
     rng = np.random.RandomState(0)
     bt = rng.permutation(P)[: B * maxp].reshape(B, maxp).astype(np.int32)
     cl = rng.randint(1, page * maxp + 1, size=(B,)).astype(np.int32)

@@ -14,11 +14,11 @@ import json
 import numpy as np
 import jax, jax.numpy as jnp
 from repro.distributed.sharding import ParallelConfig
-from repro.launch.mesh import compat_make_mesh, compat_set_mesh
 from repro.models.moe import moe_dispatch, moe_dispatch_local_ep
 
-mesh = compat_make_mesh((2, 4), ("data", "model"))
-compat_set_mesh(mesh)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+jax.set_mesh(mesh)
 pc = ParallelConfig.from_mesh(mesh)
 
 rng = np.random.RandomState(0)

@@ -46,8 +46,8 @@ def test_fragmented_and_shared_block_tables(dtype):
     P = 24
     ks = jax.random.split(KEY, 3)
     q = _rand((B, KV, Qp, hd), dtype, ks[0])
-    kp = _rand((P, page, KV, hd), dtype, ks[1])
-    vp = _rand((P, page, KV, hd), dtype, ks[2])
+    kp = _rand((P, KV, page, hd), dtype, ks[1])
+    vp = _rand((P, KV, page, hd), dtype, ks[2])
     # shared prefix: both rows reference pages [17, 3]; suffixes fragmented
     bt = np.array([[17, 3, 11, 7, 2, 19],
                    [17, 3, 5, 13, 23, 0]], np.int32)
@@ -59,8 +59,8 @@ def test_fragmented_and_shared_block_tables(dtype):
     # the gathered-page computation must equal attention over the contiguous
     # sequence each table describes
     for b in range(B):
-        k_seq = kp[bt[b]].reshape(-1, KV, hd)[: cl[b]]
-        v_seq = vp[bt[b]].reshape(-1, KV, hd)[: cl[b]]
+        k_seq = jnp.swapaxes(kp[bt[b]], 1, 2).reshape(-1, KV, hd)[: cl[b]]
+        v_seq = jnp.swapaxes(vp[bt[b]], 1, 2).reshape(-1, KV, hd)[: cl[b]]
         dense = _dense_ref(q[b], k_seq, v_seq)
         _assert_close(out[b], dense, dtype)
 
@@ -72,8 +72,8 @@ def test_ragged_context_lengths(dtype, seed):
     P = B * maxp + 3
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = _rand((B, KV, Qp, hd), dtype, ks[0])
-    kp = _rand((P, page, KV, hd), dtype, ks[1])
-    vp = _rand((P, page, KV, hd), dtype, ks[2])
+    kp = _rand((P, KV, page, hd), dtype, ks[1])
+    vp = _rand((P, KV, page, hd), dtype, ks[2])
     rng = np.random.RandomState(seed)
     bt = rng.permutation(P)[: B * maxp].reshape(B, maxp).astype(np.int32)
     # every raggedness regime: 1 token, mid-page, page boundary, full
@@ -94,8 +94,8 @@ def test_chunked_queries(dtype, num_q_tokens):
     ks = jax.random.split(KEY, 3)
     rows = num_q_tokens * Qp
     q = _rand((B, KV, rows, hd), dtype, ks[0])
-    kp = _rand((P, page, KV, hd), dtype, ks[1])
-    vp = _rand((P, page, KV, hd), dtype, ks[2])
+    kp = _rand((P, KV, page, hd), dtype, ks[1])
+    vp = _rand((P, KV, page, hd), dtype, ks[2])
     rng = np.random.RandomState(3)
     bt = rng.permutation(P)[: B * maxp].reshape(B, maxp).astype(np.int32)
     cl = np.array([page * 2 + 5, page * 4], np.int32)
@@ -123,8 +123,8 @@ def test_cow_forked_sequences():
     scratch = P - 1
 
     rng = np.random.RandomState(0)
-    kp = rng.randn(P, page, KV, hd).astype(np.float32)
-    vp = rng.randn(P, page, KV, hd).astype(np.float32)
+    kp = rng.randn(P, KV, page, hd).astype(np.float32)
+    vp = rng.randn(P, KV, page, hd).astype(np.float32)
 
     bm.allocate("parent", 6)                      # 2 pages, tail half-full
     child_alloc = bm.fork("parent", "child")
@@ -141,12 +141,12 @@ def test_cow_forked_sequences():
     kp[dst] = kp[src]                             # device-side page clone
     vp[dst] = vp[src]
     # divergent writes: child token 6, then parent token 6 — different values
-    kp[dst, 2] = 1.0
-    vp[dst, 2] = 1.0
+    kp[dst, :, 2] = 1.0
+    vp[dst, :, 2] = 1.0
     _, copy2 = bm.append_token_cow("parent")
     assert copy2 is None, "parent's tail is private after the child's CoW"
-    kp[src, 2] = -1.0
-    vp[src, 2] = -1.0
+    kp[src, :, 2] = -1.0
+    vp[src, :, 2] = -1.0
     bm.check_invariants()
 
     # both sequences now hold 7 tokens; identical prefix, divergent tail

@@ -18,12 +18,12 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.distributed.sharding import ParallelConfig, pack_q_weight, pack_kv_weight
-from repro.launch.mesh import compat_make_mesh, compat_set_mesh
 from repro.models.transformer import DenseTransformer
 from repro.models.seq_parallel import SeqParallelDenseTransformer, reshard_cache_from_packed
 
-mesh = compat_make_mesh((2, 4), ("data", "model"))
-compat_set_mesh(mesh)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+jax.set_mesh(mesh)
 pc = ParallelConfig.from_mesh(mesh)
 cfg = get_smoke_config("qwen3-1.7b").replace(num_layers=2)
 base = DenseTransformer(cfg, pc)
