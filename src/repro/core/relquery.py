@@ -44,6 +44,16 @@ class Request:
     # these preserved tokens (they are kept in ``output_tokens``), then decode
     # resumes from where it left off.
     preserved_output_tokens: int = 0
+    # Wall-clock stamps (``time.perf_counter``) the engine takes on the real
+    # path: admission, the first dispatch of its prefill, the completion of
+    # its prompt (the last one, after a preemption) and its finish. The
+    # relQuery's waiting, core and tail phases on the clock users see.
+    t_admit: Optional[float] = field(default=None, compare=False, repr=False)
+    t_prefill_dispatch: Optional[float] = field(default=None, compare=False,
+                                                repr=False)
+    t_prefill_done: Optional[float] = field(default=None, compare=False,
+                                            repr=False)
+    t_finish: Optional[float] = field(default=None, compare=False, repr=False)
 
     @property
     def num_prompt_tokens(self) -> int:

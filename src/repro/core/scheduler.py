@@ -34,6 +34,7 @@ from repro.core.priority import (
 from repro.core.relquery import RelQuery, Request, RequestState
 from repro.engine.kv_cache import SharedPrefixLedger
 from repro.engine.prefix_cache import block_hashes
+from repro.engine.tracing import span
 
 
 @dataclass
@@ -1229,7 +1230,8 @@ class SchedulerBase:
             if self.proactive_offload:
                 self._proactive_offload_tick(now)
         if self.kv_admission != "conservative":
-            self.preempt_for_headroom(now)
+            with span("relserve.preempt"):
+                self.preempt_for_headroom(now)
         return self.choose_batch(now)
 
     def refresh_priorities(self, now: float) -> None:
@@ -1533,9 +1535,10 @@ class RelServeScheduler(SchedulerBase):
 
     def refresh_priorities(self, now: float) -> None:
         import time as _time
-        t0 = _time.perf_counter()
-        self.dpu.update(self._dpu_targets(), now, self.prefix_cache)
-        self.dpu_time += _time.perf_counter() - t0
+        with span("relserve.dpu"):
+            t0 = _time.perf_counter()
+            self.dpu.update(self._dpu_targets(), now, self.prefix_cache)
+            self.dpu_time += _time.perf_counter() - t0
 
     def choose_batch(self, now: float) -> Optional[Batch]:
         import time as _time
@@ -1550,17 +1553,19 @@ class RelServeScheduler(SchedulerBase):
         if not candidates:
             return None
 
-        t0 = _time.perf_counter()
-        if self.arrangement == "adaptive":
-            decision = self.aba.choose(candidates, self.running_rqs(),
-                                       self.waiting_rqs(),
-                                       lambda r: self.relqueries[r.rel_id].priority,
-                                       now)
-        elif self.arrangement == "prefill_first":
-            decision = ArrangerDecision("prefill" if p_cand else "decode", "forced")
-        else:  # decode_first
-            decision = ArrangerDecision("decode" if d_cand else "prefill", "forced")
-        self.aba_time += _time.perf_counter() - t0
+        with span("relserve.aba"):
+            t0 = _time.perf_counter()
+            if self.arrangement == "adaptive":
+                decision = self.aba.choose(
+                    candidates, self.running_rqs(), self.waiting_rqs(),
+                    lambda r: self.relqueries[r.rel_id].priority, now)
+            elif self.arrangement == "prefill_first":
+                decision = ArrangerDecision("prefill" if p_cand else "decode",
+                                            "forced")
+            else:  # decode_first
+                decision = ArrangerDecision("decode" if d_cand else "prefill",
+                                            "forced")
+            self.aba_time += _time.perf_counter() - t0
 
         chosen = {c.kind: c for c in candidates}.get(decision.kind)
         if chosen is None:  # forced arrangement pointing at a missing candidate

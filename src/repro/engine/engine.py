@@ -36,6 +36,8 @@ import numpy as np
 from repro.core.batch import Batch
 from repro.core.relquery import RelQuery, Request
 from repro.core.scheduler import BatchResult, SchedulerBase
+from repro.engine import tracing
+from repro.engine.tracing import span
 
 ENGINE_LOOPS = ("serial", "pipelined")
 
@@ -218,6 +220,10 @@ class EngineCore:
         self.overlap_hidden_time = 0.0
         self.schedule_retries = 0
         self.iterations = 0
+        # the cost model's error while tracing is on: per tick, (the
+        # scheduler's latency-model price of the batch it chose, the
+        # executor's measured seconds)
+        self.cost_samples: List[Tuple[float, float]] = []
         # pipelined-loop speculative window (one batch deep): the pre-planned
         # next batch, the pre-projection checkpoint, the in-flight batch it
         # projected, and that batch's real (result, start, end) for flush
@@ -237,11 +243,16 @@ class EngineCore:
         per-sequence KV capacity *before* the scheduler sees them — a
         too-long request used to overflow the dense slot buffer silently
         mid-decode instead of failing here with a clear error."""
-        self._flush_plan()   # the pre-planned batch ignored this arrival
-        validate = getattr(self.executor, "validate_relquery", None)
-        if validate is not None:
-            validate(rq)
-        self.scheduler.add_relquery(rq, now)
+        with span("relserve.admit", rel_id=rq.rel_id):
+            self._flush_plan()   # the pre-planned batch ignored this arrival
+            validate = getattr(self.executor, "validate_relquery", None)
+            if validate is not None:
+                validate(rq)
+            self.scheduler.add_relquery(rq, now)
+        t = _time.perf_counter()
+        for r in rq.requests:
+            if r.t_admit is None:
+                r.t_admit = t
 
     def has_work(self) -> bool:
         return self.scheduler.has_work()
@@ -262,14 +273,19 @@ class EngineCore:
         return self._tick_serial(now)
 
     def _tick_serial(self, now: float) -> Optional[BatchEvent]:
-        batch = self._acquire_batch(now)
-        if batch is None:
-            return None
-        swap_s = self._apply_swaps(now)
-        duration, result = self.executor.execute(batch, now)
-        start, end = now, now + duration + swap_s
-        self.scheduler.complete_batch(batch, result, start, end)
-        return self._finish_tick(batch, result, start, end)
+        with tracing.tick_span(self.iterations) as tick:
+            batch = self._acquire_batch(now)
+            if batch is None:
+                return None
+            self._tag_tick(tick, batch)
+            swap_s = self._apply_swaps(now)
+            self._stamp_dispatch(batch)
+            duration, result = self.executor.execute(batch, now)
+            self._landed(batch, result, duration)
+            start, end = now, now + duration + swap_s
+            with span("relserve.complete"):
+                self.scheduler.complete_batch(batch, result, start, end)
+            return self._finish_tick(batch, result, start, end)
 
     def _tick_pipelined(self, now: float) -> Optional[BatchEvent]:
         """Dispatch → speculate → wait → reconcile. The speculative window is
@@ -279,35 +295,80 @@ class EngineCore:
         the next tick). Every ledger mutation of the window sits behind a
         checkpoint, so reconcile on a misprediction is an exact rewind plus a
         replay with the device's real result."""
-        if self._plan_cp is not None:
-            # The previous window predicted correctly: its plan is the batch
-            # to run, the window commits permanently, and executor slots of
-            # any requests the speculative schedule preempted are freed now —
-            # the same release-before-next-dispatch order as the serial loop.
-            batch = self._take_plan()
-            self._release_preempted()
-            if batch is None:
-                return None   # speculated idle (queue drained by that batch)
-        else:
-            batch = self._acquire_batch(now)
-            if batch is None:
-                return None
-        # swaps the schedule decided on (speculative ones included — a
-        # committed plan's journal survived, a flushed plan's was rolled
-        # back) land on the device before the batch that relies on them
-        swap_s = self._apply_swaps(now)
-        inflight = self.executor.dispatch(batch, now)
-        spec = self._speculate(batch, now)
-        duration, result = self.executor.wait(inflight)
-        start, end = now, now + duration + swap_s
-        if spec is not None and self._prediction_matches(spec["predicted"],
-                                                         result):
-            self._commit_speculation(spec, batch, result, start, end)
-        else:
-            if spec is not None:
-                self.scheduler.rollback(spec["cp"])
-            self.scheduler.complete_batch(batch, result, start, end)
-        return self._finish_tick(batch, result, start, end)
+        with tracing.tick_span(self.iterations) as tick:
+            if self._plan_cp is not None:
+                # The previous window predicted correctly: its plan is the
+                # batch to run, the window commits permanently, and executor
+                # slots of any requests the speculative schedule preempted
+                # are freed now — the same release-before-next-dispatch
+                # order as the serial loop.
+                batch = self._take_plan()
+                self._release_preempted()
+                if batch is None:
+                    return None   # speculated idle (queue drained by that batch)
+            else:
+                batch = self._acquire_batch(now)
+                if batch is None:
+                    return None
+            self._tag_tick(tick, batch)
+            # swaps the schedule decided on (speculative ones included — a
+            # committed plan's journal survived, a flushed plan's was rolled
+            # back) land on the device before the batch that relies on them
+            swap_s = self._apply_swaps(now)
+            self._stamp_dispatch(batch)
+            inflight = self.executor.dispatch(batch, now)
+            with span("relserve.speculate"):
+                spec = self._speculate(batch, now)
+            duration, result = self.executor.wait(inflight)
+            self._landed(batch, result, duration)
+            start, end = now, now + duration + swap_s
+            with span("relserve.complete"):
+                if spec is not None and self._prediction_matches(
+                        spec["predicted"], result):
+                    self._commit_speculation(spec, batch, result, start, end)
+                else:
+                    if spec is not None:
+                        self.scheduler.rollback(spec["cp"])
+                    self.scheduler.complete_batch(batch, result, start, end)
+            return self._finish_tick(batch, result, start, end)
+
+    @staticmethod
+    def _tag_tick(tick, batch: Batch) -> None:
+        if tick is not tracing.OFF:
+            tick.set_metadata(kind=batch.kind, rows=batch.num_requests,
+                              prefill_tokens=sum(map(batch.chunk_of,
+                                                     batch.prefill_requests)))
+
+    @staticmethod
+    def _stamp_dispatch(batch: Batch) -> None:
+        """``batch`` is about to be issued: stamp the first prefill dispatch
+        of each request it prefills (one clock read)."""
+        if batch.prefill_requests:
+            t = _time.perf_counter()
+            for r in batch.prefill_requests:
+                if r.t_prefill_dispatch is None:
+                    r.t_prefill_dispatch = t
+
+    def _landed(self, batch: Batch, result: BatchResult,
+                duration: float) -> None:
+        """A batch's result is on the host: stamp prompt completions and
+        finishes (one clock read; a request emits a token in a prefill batch
+        only when its prompt completes) and keep the cost model's price of
+        the batch beside the measured seconds while tracing is on."""
+        t = _time.perf_counter()
+        out = result.outputs
+        for r in batch.prefill_requests:
+            o = out.get(r.req_id)
+            if o is not None:
+                r.t_prefill_done = t
+                if o[1]:
+                    r.t_finish = t
+        for r in batch.decode_requests:
+            o = out.get(r.req_id)
+            if o is not None and o[1]:
+                r.t_finish = t
+        if tracing.enabled():
+            self.cost_samples.append((batch.cost(self.scheduler.lm), duration))
 
     def _acquire_batch(self, now: float) -> Optional[Batch]:
         """Schedule with the deadlock-escape retry loop (non-speculative)."""
@@ -326,7 +387,9 @@ class EngineCore:
         *round* of victims and retry. Returns (batch, deadlocked)."""
         batch = self._schedule(now)
         while batch is None and self.scheduler.has_work():
-            if not self.scheduler.preempt_for_progress(now):
+            with span("relserve.preempt"):
+                progress = self.scheduler.preempt_for_progress(now)
+            if not progress:
                 return None, True
             self.schedule_retries += 1
             batch = self._schedule(now, retry=True)
@@ -345,21 +408,22 @@ class EngineCore:
         ops = self.scheduler.drain_swap_ops()
         if not ops:
             return 0.0
-        begin = getattr(self.executor, "begin_swap_tick", None)
-        if begin is not None:
-            begin(now)
-        hooks = {
-            "out": getattr(self.executor, "swap_out", None),
-            "in": getattr(self.executor, "swap_in", None),
-            "prefetch": getattr(self.executor, "prefetch_swap_in", None),
-            "prefetch_cancel": getattr(self.executor,
-                                       "cancel_swap_prefetch", None),
-        }
-        swap_s = 0.0
-        for kind, req_id, tokens in ops:
-            hook = hooks[kind]
-            if hook is not None:
-                swap_s += hook(req_id, tokens)
+        with span("relserve.swaps", ops=len(ops)):
+            begin = getattr(self.executor, "begin_swap_tick", None)
+            if begin is not None:
+                begin(now)
+            hooks = {
+                "out": getattr(self.executor, "swap_out", None),
+                "in": getattr(self.executor, "swap_in", None),
+                "prefetch": getattr(self.executor, "prefetch_swap_in", None),
+                "prefetch_cancel": getattr(self.executor,
+                                           "cancel_swap_prefetch", None),
+            }
+            swap_s = 0.0
+            for kind, req_id, tokens in ops:
+                hook = hooks[kind]
+                if hook is not None:
+                    swap_s += hook(req_id, tokens)
         return swap_s
 
     def _check_invariants(self) -> None:
@@ -398,21 +462,23 @@ class EngineCore:
         if self.record_events:
             self.events.append(event)
         if self.on_batch is not None:
-            self.on_batch(event, batch, result)
+            with span("relserve.deliver"):
+                self.on_batch(event, batch, result)
         return event
 
     def _schedule(self, now: float, retry: bool = False) -> Optional[Batch]:
         """One timed scheduler call, then free executor slots of any requests
         the scheduler preempted while choosing (headroom or retry preemption
         both funnel through ``drain_preempt_releases``)."""
-        t0 = _time.perf_counter()
-        batch = self.scheduler.schedule(now)
-        dt = _time.perf_counter() - t0
-        if retry:
-            self.schedule_retry_time += dt
-        else:
-            self.schedule_time += dt
-        self._release_preempted()
+        with span("relserve.schedule"):
+            t0 = _time.perf_counter()
+            batch = self.scheduler.schedule(now)
+            dt = _time.perf_counter() - t0
+            if retry:
+                self.schedule_retry_time += dt
+            else:
+                self.schedule_time += dt
+            self._release_preempted()
         return batch
 
     # ------------------------------------------------------- speculative window

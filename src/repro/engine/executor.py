@@ -41,6 +41,7 @@ from repro.core.relquery import RelQuery, Request
 from repro.core.scheduler import BatchResult
 from repro.engine.kv_cache import BlockManager, OutOfBlocks
 from repro.engine.prefix_cache import PrefixCache, block_hashes
+from repro.engine.tracing import span, traced
 
 
 class RequestCapacityError(ValueError):
@@ -104,9 +105,6 @@ class _ExecutorBase:
         self.greedy = greedy
         self.prefill_samples: List[Tuple[int, float]] = []
         self.decode_samples: List[Tuple[int, float]] = []
-        # compile seconds spent pre-staging shape buckets during another
-        # batch's device compute (never charged to any batch duration)
-        self.prestage_compile_s = 0.0
         # every ahead-of-time compile this executor ran, and their seconds
         self.compile_count = 0
         self.compile_s_total = 0.0
@@ -128,23 +126,43 @@ class _ExecutorBase:
                     f"the executor with a larger max_len")
 
     # ------------------------------------------------------------- shared bits
-    def _aot(self, fn, *args) -> Tuple[object, float]:
+    def _aot(self, fn, *args, shape=None) -> Tuple[object, float]:
         """Ahead-of-time compile ``fn`` for ``args``; returns (executable,
         compile_seconds). Callers subtract the compile time from their
         measured phase duration: throughput samples and the fitted cost model
         must see steady-state execution, not first-shape XLA compilation
         (the shape-bucketed paged backend compiles several decode variants
         over a run — charging those to decode latency would skew both the
-        clock and Fig. 7's α/β fit)."""
-        t0 = _time.perf_counter()
-        exe = fn.lower(*args).compile()
-        dt = _time.perf_counter() - t0
+        clock and Fig. 7's α/β fit). The ``relserve.compile`` span names the
+        program and its shape key (``shape``)."""
+        with span("relserve.compile", program=fn.__name__, shape=str(shape)):
+            t0 = _time.perf_counter()
+            exe = fn.lower(*args).compile()
+            dt = _time.perf_counter() - t0
         self.compile_count += 1
         self.compile_s_total += dt
         return exe, dt
 
+    def _prefill_program(self, max_len: int):
+        """The jitted prefill program, named ``prefill_step`` in its XLA
+        module, for prompts padded to ``max_len``."""
+        model = self.model
+
+        def prefill_step(params, tokens, seq_lens):
+            return model.prefill(params, tokens, seq_lens=seq_lens,
+                                 max_len=max_len)
+        return jax.jit(prefill_step)
+
     def _sample(self, logits) -> np.ndarray:
         return np.asarray(jnp.argmax(logits, axis=-1))
+
+    @staticmethod
+    def _release(req_ids: List[str], free) -> None:
+        """Free what the requests that finished in this batch hold."""
+        if req_ids:
+            with span("relserve.release"):
+                for req_id in req_ids:
+                    free(req_id)
 
     def _is_finish_token(self, r: Request, tok: int, produced: int) -> bool:
         if r.eos_token is not None and tok == r.eos_token:
@@ -303,15 +321,15 @@ class RealExecutor(_ExecutorBase):
             lambda x: None if isinstance(x, str) else jnp.asarray(x), stash)
         args = (self.cache, src, jnp.asarray(i, jnp.int32))
         if self._put_fn is None:
-            def put(cache, src, slot):
+            def swap_put(cache, src, slot):
                 return jax.tree.map(
                     lambda dst, x: dst if x is None else
                     jax.lax.dynamic_update_slice_in_dim(
                         dst, x.astype(dst.dtype), slot,
                         axis=self._slot_axis(dst)),
                     cache, src)
-            self._put_fn, dt = self._aot(jax.jit(put, donate_argnums=(0,)),
-                                         *args)
+            self._put_fn, dt = self._aot(
+                jax.jit(swap_put, donate_argnums=(0,)), *args)
             self._compile_s += dt
         self.cache = self._put_fn(*args)
         self.slots[i].position = position
@@ -348,9 +366,8 @@ class RealExecutor(_ExecutorBase):
         toks[0, :n] = seq
         args = (self.params, jnp.asarray(toks), jnp.asarray([n], jnp.int32))
         if bucket not in self._prefill_fn:
-            fn = jax.jit(lambda p, t, sl: self.model.prefill(
-                p, t, seq_lens=sl, max_len=self.max_len))
-            self._prefill_fn[bucket], dt = self._aot(fn, *args)
+            self._prefill_fn[bucket], dt = self._aot(
+                self._prefill_program(self.max_len), *args, shape=bucket)
             self._compile_s += dt
         logits, kv = self._prefill_fn[bucket](*args)
         slot = self._alloc_slot(req)
@@ -374,10 +391,8 @@ class RealExecutor(_ExecutorBase):
             toks = np.zeros((1, bucket), np.int32)
             args = (self.params, jnp.asarray(toks),
                     jnp.asarray([n], jnp.int32))
-            fn = jax.jit(lambda p, t, sl: self.model.prefill(
-                p, t, seq_lens=sl, max_len=self.max_len))
-            self._prefill_fn[bucket], dt = self._aot(fn, *args)
-            self.prestage_compile_s += dt
+            self._prefill_fn[bucket], _ = self._aot(
+                self._prefill_program(self.max_len), *args, shape=bucket)
 
     def _write_slot_cache(self, slot: int, kv) -> None:
         """Copy a single-sequence prefill cache into slot ``slot``, in place
@@ -400,10 +415,10 @@ class RealExecutor(_ExecutorBase):
         args = (self.cache, kv, jnp.asarray(slot, jnp.int32))
         key = tuple(x.shape for x in jax.tree.leaves(kv))
         if key not in self._write_fn:
-            fn = jax.jit(lambda cache, kv, slot: jax.tree.map(
-                lambda d, s: write(d, s, slot), cache, kv),
-                donate_argnums=(0,))
-            self._write_fn[key], dt = self._aot(fn, *args)
+            def slot_write(cache, kv, slot):
+                return jax.tree.map(lambda d, s: write(d, s, slot), cache, kv)
+            self._write_fn[key], dt = self._aot(
+                jax.jit(slot_write, donate_argnums=(0,)), *args, shape=key)
             self._compile_s += dt
         self.cache = self._write_fn[key](*args)
 
@@ -434,7 +449,8 @@ class RealExecutor(_ExecutorBase):
         args = (self.params, self.cache, jnp.asarray(tokens),
                 jnp.asarray(positions))
         if self._decode_fn is None:
-            self._decode_fn, dt = self._aot(self._decode_jit, *args)
+            self._decode_fn, dt = self._aot(self._decode_jit, *args,
+                                            shape=self.max_slots)
             self._compile_s += dt
         logits, self.cache = self._decode_fn(*args)
         for r in reqs:
@@ -442,6 +458,7 @@ class RealExecutor(_ExecutorBase):
         return logits
 
     # ------------------------------------------------------------------ engine API
+    @traced("relserve.dispatch")
     def dispatch(self, batch: Batch, now: float) -> InFlight:
         """Issue one unified batch on the device without blocking: prefill
         passes write their KV and the decode step advances the slot
@@ -452,19 +469,21 @@ class RealExecutor(_ExecutorBase):
         t0 = _time.perf_counter()
         pending = []
         total_utok = 0
-        for r in batch.prefill_requests:
-            if not batch.completes_prompt(r):
-                continue  # chunk not finishing the prompt: accounted only
-            logits, utok = self._prefill_issue(r)
-            total_utok += utok
-            pending.append((r, logits))
+        with span("relserve.prefill_issue"):
+            for r in batch.prefill_requests:
+                if not batch.completes_prompt(r):
+                    continue  # chunk not finishing the prompt: accounted only
+                logits, utok = self._prefill_issue(r)
+                total_utok += utok
+                pending.append((r, logits))
         prefill_issue = max(0.0, _time.perf_counter() - t0 - self._compile_s)
         reqs = [r for r in batch.decode_requests if r.req_id in self._slot_of]
         decode_logits, rows, decode_issue = None, [], 0.0
         if reqs:
             self._compile_s = 0.0
             t1 = _time.perf_counter()
-            decode_logits = self._decode_issue(reqs)
+            with span("relserve.decode_issue"):
+                decode_logits = self._decode_issue(reqs)
             # capture logits rows now: a prefill request finishing in wait()
             # frees its own slot only, so these stay valid either way
             rows = [self._slot_of[r.req_id] for r in reqs]
@@ -478,6 +497,7 @@ class RealExecutor(_ExecutorBase):
                         prefill_issue_s=prefill_issue,
                         decode_issue_s=decode_issue, produced=produced)
 
+    @traced("relserve.wait")
     def wait(self, inflight: InFlight) -> Tuple[float, BatchResult]:
         """Materialize a dispatched batch: sample every pending logits row
         (the blocking host transfer), detect finishes and free their slots.
@@ -487,21 +507,27 @@ class RealExecutor(_ExecutorBase):
         prefill_dur = inflight.prefill_issue_s
         if inflight.prefill_pending:
             t0 = _time.perf_counter()
-            for r, logits in inflight.prefill_pending:
-                tok = int(self._sample(logits)[0])
+            with span("relserve.prefill_sync"):
+                toks = [int(self._sample(logits)[0])
+                        for _, logits in inflight.prefill_pending]
+            done = []
+            for (r, _), tok in zip(inflight.prefill_pending, toks):
                 # a restarted (preempted) request already produced its
                 # preserved tokens; this prefill emits the (len + 1)-th
                 finished = self._is_finish_token(r, tok,
                                                  inflight.produced[r.req_id])
                 outputs[r.req_id] = (tok, finished)
                 if finished:
-                    self._free_slot(r.req_id)
+                    done.append(r.req_id)
+            self._release(done, self._free_slot)
             prefill_dur += _time.perf_counter() - t0
             self.prefill_samples.append((inflight.utok, prefill_dur))
         decode_dur = inflight.decode_issue_s
         if inflight.decode_pending is not None:
             t1 = _time.perf_counter()
-            out = self._sample(inflight.decode_pending)
+            with span("relserve.decode_sync"):
+                out = self._sample(inflight.decode_pending)
+            done = []
             for r, row in zip(inflight.decode_reqs, inflight.decode_rows):
                 tok = int(out[row])
                 # ``produced`` was counted at dispatch, when output_tokens
@@ -512,10 +538,12 @@ class RealExecutor(_ExecutorBase):
                                                  inflight.produced[r.req_id])
                 outputs[r.req_id] = (tok, finished)
                 if finished:
-                    self._free_slot(r.req_id)
+                    done.append(r.req_id)
+            self._release(done, self._free_slot)
             decode_dur += _time.perf_counter() - t1
             self.decode_samples.append((len(inflight.decode_reqs), decode_dur))
-        self._materialize_host_stash()
+        with span("relserve.host_stash"):
+            self._materialize_host_stash()
         return prefill_dur + decode_dur, BatchResult(outputs)
 
     def execute(self, batch: Batch, now: float) -> Tuple[float, BatchResult]:
@@ -742,10 +770,8 @@ class PagedRealExecutor(_ExecutorBase):
             toks = np.zeros((B, L), np.int32)
             args = (self.params, jnp.asarray(toks),
                     jnp.asarray(np.ones((B,), np.int32)))
-            fn = jax.jit(lambda p, t, sl, L=L: self.model.prefill(
-                p, t, seq_lens=sl, max_len=L))
-            self._prefill_fn[key], dt = self._aot(fn, *args)
-            self.prestage_compile_s += dt
+            self._prefill_fn[key], _ = self._aot(self._prefill_program(L),
+                                                 *args, shape=key)
 
     # ------------------------------------------------------------- prefill
     def _prefill_issue_batch(self, reqs: List[Request]) -> Tuple[List, int]:
@@ -800,16 +826,20 @@ class PagedRealExecutor(_ExecutorBase):
             key = (B, L)
             args = (self.params, jnp.asarray(toks), jnp.asarray(seq_lens))
             if key not in self._prefill_fn:
-                fn = jax.jit(lambda p, t, sl, L=L: self.model.prefill(
-                    p, t, seq_lens=sl, max_len=L))
-                self._prefill_fn[key], dt = self._aot(fn, *args)
+                self._prefill_fn[key], dt = self._aot(
+                    self._prefill_program(L), *args, shape=key)
                 self._compile_s += dt
             logits, caches = self._prefill_fn[key](*args)
             sargs = (self.pools, caches, jnp.asarray(tables))
             if key not in self._scatter_fn:
-                fn = jax.jit(self.model.scatter_prefill_pools,
-                             donate_argnums=(0,))
-                self._scatter_fn[key], dt = self._aot(fn, *sargs)
+                model = self.model
+
+                def scatter_prefill(pools, caches, block_tables):
+                    return model.scatter_prefill_pools(pools, caches,
+                                                       block_tables)
+                self._scatter_fn[key], dt = self._aot(
+                    jax.jit(scatter_prefill, donate_argnums=(0,)), *sargs,
+                    shape=key)
                 self._compile_s += dt
             self.pools = self._scatter_fn[key](*sargs)
             pending.append((grp, logits))
@@ -822,7 +852,7 @@ class PagedRealExecutor(_ExecutorBase):
         args = (self.pools, jnp.asarray(src, jnp.int32),
                 jnp.asarray(dst, jnp.int32))
         if self._copy_fn is None:
-            def copy(pools, s, d):
+            def copy_block(pools, s, d):
                 pools = dict(pools)
                 for name in ("k", "v"):
                     pools[name] = jax.lax.dynamic_update_index_in_dim(
@@ -831,8 +861,8 @@ class PagedRealExecutor(_ExecutorBase):
                                                      keepdims=False),
                         d, axis=2)
                 return pools
-            self._copy_fn, dt = self._aot(jax.jit(copy, donate_argnums=(0,)),
-                                          *args)
+            self._copy_fn, dt = self._aot(
+                jax.jit(copy_block, donate_argnums=(0,)), *args)
             self._compile_s += dt
         self.pools = self._copy_fn(*args)
         self.cow_copies += 1
@@ -869,16 +899,21 @@ class PagedRealExecutor(_ExecutorBase):
         args = (self.params, self.pools, jnp.asarray(tokens),
                 jnp.asarray(pos_arr), jnp.asarray(tables), jnp.asarray(ctx))
         if key not in self._decode_fn:
-            fn = jax.jit(
-                lambda p, pools, t, po, bt, cl: self.model.decode_step_paged(
-                    p, pools, t, po, bt, cl, attn_impl=self.attn_impl),
-                donate_argnums=(1,))
-            self._decode_fn[key], dt = self._aot(fn, *args)
+            model, impl = self.model, self.attn_impl
+
+            def decode_step(params, pools, tokens, positions, block_tables,
+                            context_lens):
+                return model.decode_step_paged(
+                    params, pools, tokens, positions, block_tables,
+                    context_lens, attn_impl=impl)
+            self._decode_fn[key], dt = self._aot(
+                jax.jit(decode_step, donate_argnums=(1,)), *args, shape=key)
             self._compile_s += dt
         logits, self.pools = self._decode_fn[key](*args)
         return logits
 
     # ------------------------------------------------------------- engine API
+    @traced("relserve.dispatch")
     def dispatch(self, batch: Batch, now: float) -> InFlight:
         """Issue one unified batch: block allocation, prefill + pool scatter
         and the paged decode step all run host-side/async; logits stay on the
@@ -895,7 +930,8 @@ class PagedRealExecutor(_ExecutorBase):
         if prefill_reqs:
             self._compile_s = 0.0
             t0 = _time.perf_counter()
-            pending, utok = self._prefill_issue_batch(prefill_reqs)
+            with span("relserve.prefill_issue"):
+                pending, utok = self._prefill_issue_batch(prefill_reqs)
             prefill_issue = max(0.0,
                                 _time.perf_counter() - t0 - self._compile_s)
         reqs = [r for r in batch.decode_requests if r.req_id in self._active]
@@ -903,7 +939,8 @@ class PagedRealExecutor(_ExecutorBase):
         if reqs:
             self._compile_s = 0.0
             t1 = _time.perf_counter()
-            decode_logits = self._decode_issue(reqs)
+            with span("relserve.decode_issue"):
+                decode_logits = self._decode_issue(reqs)
             decode_issue = max(0.0,
                                _time.perf_counter() - t1 - self._compile_s)
         produced = {r.req_id: len(r.output_tokens) + 1
@@ -914,6 +951,7 @@ class PagedRealExecutor(_ExecutorBase):
                         prefill_issue_s=prefill_issue,
                         decode_issue_s=decode_issue, produced=produced)
 
+    @traced("relserve.wait")
     def wait(self, inflight: InFlight) -> Tuple[float, BatchResult]:
         """Same phase-separated timing contract as the dense executor:
         sample each prefill group then the decode step, free the blocks of
@@ -922,31 +960,39 @@ class PagedRealExecutor(_ExecutorBase):
         prefill_dur = inflight.prefill_issue_s
         if inflight.prefill_pending:
             t0 = _time.perf_counter()
-            for grp, logits in inflight.prefill_pending:
-                out_tokens = self._sample(logits)
+            with span("relserve.prefill_sync"):
+                sampled = [self._sample(logits)
+                           for _, logits in inflight.prefill_pending]
+            done = []
+            for (grp, _), out_tokens in zip(inflight.prefill_pending, sampled):
                 for i, r in enumerate(grp):
                     tok = int(out_tokens[i])
                     finished = self._is_finish_token(r, tok,
                                                      inflight.produced[r.req_id])
                     outputs[r.req_id] = (tok, finished)
                     if finished:
-                        self.release_request(r.req_id)
+                        done.append(r.req_id)
+            self._release(done, self.release_request)
             prefill_dur += _time.perf_counter() - t0
             self.prefill_samples.append((inflight.utok, prefill_dur))
         decode_dur = inflight.decode_issue_s
         if inflight.decode_pending is not None:
             t1 = _time.perf_counter()
-            out = self._sample(inflight.decode_pending)
+            with span("relserve.decode_sync"):
+                out = self._sample(inflight.decode_pending)
+            done = []
             for i, r in enumerate(inflight.decode_reqs):
                 tok = int(out[i])
                 finished = self._is_finish_token(r, tok,
                                                  inflight.produced[r.req_id])
                 outputs[r.req_id] = (tok, finished)
                 if finished:
-                    self.release_request(r.req_id)
+                    done.append(r.req_id)
+            self._release(done, self.release_request)
             decode_dur += _time.perf_counter() - t1
             self.decode_samples.append((len(inflight.decode_reqs), decode_dur))
-        self._materialize_host_stash()
+        with span("relserve.host_stash"):
+            self._materialize_host_stash()
         return prefill_dur + decode_dur, BatchResult(outputs)
 
     def execute(self, batch: Batch, now: float) -> Tuple[float, BatchResult]:

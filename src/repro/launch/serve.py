@@ -27,10 +27,14 @@ bit-identical to the unplanned replay.
   PYTHONPATH=src python -m repro.launch.serve --simulate --plan full \
       --dup-row-fraction 0.5 --prefix-sharing on
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b-smoke --num-relqueries 4
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b-smoke \
+      --kv-backend paged --profile-dir /tmp/relserve-trace
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+from typing import Optional
 
 from repro.core.latency_model import a100_opt13b
 from repro.core.policies import SCHEDULERS
@@ -235,6 +239,27 @@ def run_elastic_replay(frontend: Frontend, cluster, trace,
     return cluster.report()
 
 
+@contextlib.contextmanager
+def _profiled(log_dir: Optional[str]):
+    """Run the body under the JAX profiler with the engine's ``relserve.*``
+    spans on, writing the trace under ``log_dir`` (nothing when None)."""
+    if log_dir is None:
+        yield
+        return
+    import jax
+
+    from repro.engine import tracing
+
+    tracing.enable()
+    jax.profiler.start_trace(log_dir)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+        tracing.disable()
+        print(f"profile: trace with relserve.* spans under {log_dir}")
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--scheduler", default="relserve", choices=list(SCHEDULERS))
@@ -376,6 +401,10 @@ def main() -> None:
     ap.add_argument("--metrics-interval", type=float, default=5.0,
                     help="simulated seconds between --metrics-log samples")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="real mode: record a JAX profiler trace of the run "
+                         "under DIR, with the engine's relserve.* spans on "
+                         "(open it with TensorBoard or Perfetto)")
     args = ap.parse_args()
 
     if args.num_replicas < 1:
@@ -443,6 +472,9 @@ def main() -> None:
                          f"(got {args.snapshot_every})")
     if args.snapshot_every is not None and not args.simulate:
         raise SystemExit("--snapshot-every only applies with --simulate")
+    if args.profile_dir is not None and args.simulate:
+        raise SystemExit("--profile-dir traces the real executors; drop "
+                         "--simulate")
     if args.metrics_interval <= 0:
         raise SystemExit(f"--metrics-interval must be > 0 s "
                          f"(got {args.metrics_interval})")
@@ -570,16 +602,17 @@ def main() -> None:
             raise SystemExit(f"--kv-backend {args.kv_backend}: {e}")
         print(f"scheduler={args.scheduler} kv-backend={args.kv_backend} "
               f"engine-loop={args.engine_loop} kv-tiering={args.kv_tiering}")
-        if args.open_loop:
-            report = run_open_loop(Frontend(engine), trace)
-            _print_report("open-loop", report)
-        elif args.plan != "off":
-            report = run_planned(Frontend(engine), trace, args.plan,
-                                 tokenizer=tok)
-            _print_report("planned", report)
-        else:
-            report = engine.run_trace(trace)
-            _print_report("merged", report)
+        with _profiled(args.profile_dir):
+            if args.open_loop:
+                report = run_open_loop(Frontend(engine), trace)
+                _print_report("open-loop", report)
+            elif args.plan != "off":
+                report = run_planned(Frontend(engine), trace, args.plan,
+                                     tokenizer=tok)
+                _print_report("planned", report)
+            else:
+                report = engine.run_trace(trace)
+                _print_report("merged", report)
 
     print(f"overheads: DPU {report.dpu_time:.3f}s  ABA {report.aba_time:.3f}s  "
           f"schedule {report.schedule_time:.3f}s  "

@@ -27,6 +27,11 @@ from repro.models.param_utils import (
 
 LOCAL_ROPE_THETA = 10_000.0  # gemma3 uses short-rope on sliding-window layers
 
+# Named scopes of the serving steps (metadata only: each op's name in the
+# lowered program and the device trace carries its scope, the compiled code
+# is the same): embed, qkv, kv_write, attn, mlp, logits.
+scope = jax.named_scope
+
 
 class DenseTransformer:
     """Functional model: params are an explicit pytree, methods are pure."""
@@ -191,12 +196,13 @@ class DenseTransformer:
         ``block_tables`` ``[B, L // block_size]`` routes each block — pad rows
         and pad blocks should point at the scratch block."""
         bs = pools["k"].shape[4]
-        for name in ("k", "v"):
-            c = caches[f"{name}_full"]
-            G, NF, B, L, KVs, hd = c.shape
-            c = c.reshape(G, NF, B, L // bs, bs, KVs, hd).swapaxes(4, 5)
-            pools[name] = pools[name].at[:, :, block_tables].set(
-                c.astype(pools[name].dtype))
+        with scope("kv_write"):
+            for name in ("k", "v"):
+                c = caches[f"{name}_full"]
+                G, NF, B, L, KVs, hd = c.shape
+                c = c.reshape(G, NF, B, L // bs, bs, KVs, hd).swapaxes(4, 5)
+                pools[name] = pools[name].at[:, :, block_tables].set(
+                    c.astype(pools[name].dtype))
         return pools
 
     def decode_step_paged(self, params, pools, tokens, positions,
@@ -212,12 +218,11 @@ class DenseTransformer:
         (``'ref'`` — the CPU path the tests run). Returns (logits, pools);
         pools should be donated by the jit wrapper.
         """
-        from repro.kernels.paged_attention import paged_attention
-
         cfg = self.cfg
         bs = pools["k"].shape[4]
         B = tokens.shape[0]
-        x = self.embed_tokens(params, tokens)
+        with scope("embed"):
+            x = self.embed_tokens(params, tokens)
         pools = dict(pools)
         rows = jnp.arange(B)
         bids = block_tables[rows, positions // bs]
@@ -225,37 +230,49 @@ class DenseTransformer:
         for g in range(self.n_groups):
             pp = jax.tree.map(lambda a: a[g], params["blocks"])
             for p in range(self.group):
-                h = L.rmsnorm(x, pp["ln1"][p], cfg.norm_eps)
-                q, k, v = self._qkv(pp, p, h, positions, "global")
+                with scope("qkv"):
+                    h = L.rmsnorm(x, pp["ln1"][p], cfg.norm_eps)
+                    q, k, v = self._qkv(pp, p, h, positions, "global")
                 i = self.full_idx[p]
-                pools["k"] = pools["k"].at[g, i, bids, :, offs].set(
-                    k.astype(pools["k"].dtype))
-                pools["v"] = pools["v"].at[g, i, bids, :, offs].set(
-                    v.astype(pools["v"].dtype))
-                if attn_impl == "ref":
-                    # CPU path: gather the sequence's pages into a dense
-                    # [B, T, G, hd] view and run the *exact* dense decode
-                    # recipe (same dtype roundings, same masking) — paged and
-                    # dense backends then emit bit-identical tokens even in
-                    # bf16, while T stays the bucketed block span instead of
-                    # max_len.
-                    kg = pools["k"][g, i][block_tables].swapaxes(2, 3)
-                    vg = pools["v"][g, i][block_tables].swapaxes(2, 3)
-                    Bq, NB, bsz, KVs, hd = kg.shape       # [B, NB, bs, KVs, hd]
-                    o = L.decode_attention(
-                        q, kg.reshape(Bq, NB * bsz, KVs, hd),
-                        vg.reshape(Bq, NB * bsz, KVs, hd), positions)
-                else:
-                    o = paged_attention(q, pools["k"][g, i], pools["v"][g, i],
-                                        block_tables, context_lens,
-                                        interpret=attn_impl != "pallas")
-                x = x + jnp.einsum("bgqh,gqhd->bd", o, pp["wo"][p])
-                h = L.rmsnorm(x, pp["ln2"][p], cfg.norm_eps)
-                mlp, _ = self._mlp(pp, p, h)
-                x = x + mlp
+                with scope("kv_write"):
+                    pools["k"] = pools["k"].at[g, i, bids, :, offs].set(
+                        k.astype(pools["k"].dtype))
+                    pools["v"] = pools["v"].at[g, i, bids, :, offs].set(
+                        v.astype(pools["v"].dtype))
+                with scope("attn"):
+                    o = self._paged_attn(q, pools["k"][g, i], pools["v"][g, i],
+                                         block_tables, context_lens,
+                                         positions, attn_impl)
+                    x = x + jnp.einsum("bgqh,gqhd->bd", o, pp["wo"][p])
+                with scope("mlp"):
+                    h = L.rmsnorm(x, pp["ln2"][p], cfg.norm_eps)
+                    mlp, _ = self._mlp(pp, p, h)
+                    x = x + mlp
                 x = self._constrain(x, "batch", None)
-        x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        return self.logits(params, x), pools
+        with scope("logits"):
+            x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+            return self.logits(params, x), pools
+
+    @staticmethod
+    def _paged_attn(q, k_pool, v_pool, block_tables, context_lens, positions,
+                    attn_impl: str):
+        """One layer's decode attention over its K/V pools."""
+        from repro.kernels.paged_attention import paged_attention
+
+        if attn_impl == "ref":
+            # CPU path: gather the sequence's pages into a dense
+            # [B, T, G, hd] view and run the *exact* dense decode recipe
+            # (same dtype roundings, same masking) — paged and dense
+            # backends then emit bit-identical tokens even in bf16, while T
+            # stays the bucketed block span instead of max_len.
+            kg = k_pool[block_tables].swapaxes(2, 3)
+            vg = v_pool[block_tables].swapaxes(2, 3)
+            Bq, NB, bsz, KVs, hd = kg.shape       # [B, NB, bs, KVs, hd]
+            return L.decode_attention(
+                q, kg.reshape(Bq, NB * bsz, KVs, hd),
+                vg.reshape(Bq, NB * bsz, KVs, hd), positions)
+        return paged_attention(q, k_pool, v_pool, block_tables, context_lens,
+                               interpret=attn_impl != "pallas")
 
     # ------------------------------------------------------------- building blocks
     def _constrain(self, x, *logical):
@@ -292,8 +309,14 @@ class DenseTransformer:
 
     def _mixer_seq(self, pp, p: int, x, positions, seq_lens, kind: str, state):
         """Sequence-mode token mixer (attention). Returns (out, cache_entry)."""
+        with scope("qkv"):
+            q, k, v = self._qkv(pp, p, x, positions, kind)
+        with scope("attn"):
+            return self._attn_seq(pp, p, q, k, v, seq_lens, kind)
+
+    def _attn_seq(self, pp, p: int, q, k, v, seq_lens, kind: str):
+        """Sequence-mode attention and its output projection."""
         cfg = self.cfg
-        q, k, v = self._qkv(pp, p, x, positions, kind)
         window = cfg.sliding_window if kind == "local" else 0
         if self.prefill_attn_impl == "flash":
             # Pallas flash_prefill kernel: causal masking alone suffices for
@@ -337,17 +360,20 @@ class DenseTransformer:
                              cache, g: int):
         """Decode attention with scatter-in-place KV writes on the full cache."""
         cfg = self.cfg
-        q, k, v = self._qkv(pp, p, x, positions, kind)
+        with scope("qkv"):
+            q, k, v = self._qkv(pp, p, x, positions, kind)
         window = cfg.sliding_window if kind == "local" else 0
         if kind == "global":
             i, kk, vk = self.full_idx[p], "k_full", "v_full"
         else:
             i, kk, vk = self.win_idx[p], "k_win", "v_win"
-        cache[kk] = L.cache_write_full(cache[kk], g, i, k, positions, window)
-        cache[vk] = L.cache_write_full(cache[vk], g, i, v, positions, window)
-        o = L.decode_attention(q, cache[kk][g, i], cache[vk][g, i],
-                               positions, window=window)
-        out = jnp.einsum("bgqh,gqhd->bd", o, pp["wo"][p])
+        with scope("kv_write"):
+            cache[kk] = L.cache_write_full(cache[kk], g, i, k, positions, window)
+            cache[vk] = L.cache_write_full(cache[vk], g, i, v, positions, window)
+        with scope("attn"):
+            o = L.decode_attention(q, cache[kk][g, i], cache[vk][g, i],
+                                   positions, window=window)
+            out = jnp.einsum("bgqh,gqhd->bd", o, pp["wo"][p])
         return out, cache
 
     # ------------------------------------------------------------- forward (seq mode)
@@ -358,15 +384,19 @@ class DenseTransformer:
         W = min(cfg.sliding_window or max_len, max_len)
         for p in range(self.group):
             kind = self.kinds[p]
-            h = L.rmsnorm(x, pp["ln1"][p], cfg.norm_eps)
+            with scope("qkv"):
+                h = L.rmsnorm(x, pp["ln1"][p], cfg.norm_eps)
             attn, (k, v) = self._mixer_seq(pp, p, h, positions, seq_lens, kind, None)
             x = x + attn
-            h = L.rmsnorm(x, pp["ln2"][p], cfg.norm_eps)
-            mlp, a = self._mlp(pp, p, h)
-            x = x + mlp
+            with scope("mlp"):
+                h = L.rmsnorm(x, pp["ln2"][p], cfg.norm_eps)
+                mlp, a = self._mlp(pp, p, h)
+                x = x + mlp
             aux = aux + a
             x = self._constrain(x, "batch", None, None)
-            if collect:
+            if not collect:
+                continue
+            with scope("kv_write"):
                 if kind == "global":
                     S = k.shape[1]
                     pad = max_len - S
@@ -379,10 +409,11 @@ class DenseTransformer:
                     kw.append(L.ring_from_sequence(k, W, seq_lens))
                     vw.append(L.ring_from_sequence(v, W, seq_lens))
         caches = {}
-        if collect and kf:
-            caches["k_full"], caches["v_full"] = jnp.stack(kf), jnp.stack(vf)
-        if collect and kw:
-            caches["k_win"], caches["v_win"] = jnp.stack(kw), jnp.stack(vw)
+        with scope("kv_write"):
+            if kf:
+                caches["k_full"], caches["v_full"] = jnp.stack(kf), jnp.stack(vf)
+            if kw:
+                caches["k_win"], caches["v_win"] = jnp.stack(kw), jnp.stack(vw)
         return (x, aux), caches
 
     def forward_hidden(self, params, embeds, positions, seq_lens=None, *,
@@ -440,7 +471,8 @@ class DenseTransformer:
         """Returns (last-token logits [B, V], cache). ``extra_embeds`` are
         prepended patch/frame embeddings (VLM stub frontend)."""
         B, S_tok = tokens.shape
-        embeds = self.embed_tokens(params, tokens)
+        with scope("embed"):
+            embeds = self.embed_tokens(params, tokens)
         if extra_embeds is not None:
             embeds = jnp.concatenate([extra_embeds.astype(self._dtype), embeds], axis=1)
         S = embeds.shape[1]
@@ -448,12 +480,14 @@ class DenseTransformer:
         max_len = max_len or S
         hidden, _, caches = self.forward_hidden(
             params, embeds, positions, seq_lens, collect_cache=True, max_len=max_len)
-        if seq_lens is not None:
-            last = jnp.take_along_axis(
-                hidden, (seq_lens - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        else:
-            last = hidden[:, -1]
-        return self.logits(params, last), caches
+        with scope("logits"):
+            if seq_lens is not None:
+                last = jnp.take_along_axis(
+                    hidden, (seq_lens - 1)[:, None, None].astype(jnp.int32),
+                    axis=1)[:, 0]
+            else:
+                last = hidden[:, -1]
+            return self.logits(params, last), caches
 
     def decode_step(self, params, cache, tokens, positions):
         """tokens: [B] int32, positions: [B] -> (logits [B, V], new cache).
@@ -463,22 +497,26 @@ class DenseTransformer:
         no scan ys double-buffering of the multi-GB cache arrays.
         """
         cfg = self.cfg
-        x = self.embed_tokens(params, tokens)
+        with scope("embed"):
+            x = self.embed_tokens(params, tokens)
         cache = dict(cache)
         for g in range(self.n_groups):
             pp = jax.tree.map(lambda a: a[g], params["blocks"])
             for p in range(self.group):
                 kind = self.kinds[p]
-                h = L.rmsnorm(x, pp["ln1"][p], cfg.norm_eps)
+                with scope("qkv"):
+                    h = L.rmsnorm(x, pp["ln1"][p], cfg.norm_eps)
                 attn, cache = self._attn_decode_inplace(pp, p, h, positions,
                                                         kind, cache, g)
                 x = x + attn
-                h = L.rmsnorm(x, pp["ln2"][p], cfg.norm_eps)
-                mlp, _ = self._mlp(pp, p, h)
-                x = x + mlp
+                with scope("mlp"):
+                    h = L.rmsnorm(x, pp["ln2"][p], cfg.norm_eps)
+                    mlp, _ = self._mlp(pp, p, h)
+                    x = x + mlp
                 x = self._constrain(x, "batch", None)
-        x = L.rmsnorm(x, params["final_norm"], self.cfg.norm_eps)
-        return self.logits(params, x), cache
+        with scope("logits"):
+            x = L.rmsnorm(x, params["final_norm"], self.cfg.norm_eps)
+            return self.logits(params, x), cache
 
     # ------------------------------------------------------------- roofline support
     def with_layers(self, num_layers: int) -> "DenseTransformer":

@@ -1,5 +1,6 @@
 """launch/serve.py CLI: argument validation fails fast with clear messages,
 and the --open-loop smoke mode exercises submit/stream/cancel/snapshot."""
+import glob
 import sys
 
 import pytest
@@ -28,6 +29,8 @@ def _run(monkeypatch, *argv):
       "--host-kv-cap", "0"], "--host-kv-cap must be >= 1"),
     (["--simulate", "--kv-tiering", "on", "--kv-admission", "optimistic",
       "--swap-bandwidth", "0"], "--swap-bandwidth must be > 0 GB/s"),
+    (["--simulate", "--profile-dir", "trace"],
+     "--profile-dir traces the real executors"),
 ])
 def test_cli_validation(monkeypatch, argv, match):
     with pytest.raises(SystemExit, match=match):
@@ -67,3 +70,23 @@ def test_closed_loop_simulated_still_works(monkeypatch, capsys):
          "--max-requests", "8", "--rate", "4.0")
     out = capsys.readouterr().out
     assert "[merged] relqueries=8" in out
+
+
+def test_profile_dir_records_the_program_spans(monkeypatch, capsys, tmp_path):
+    """Real mode under --profile-dir writes one profiler trace holding the
+    engine's relserve.* spans, and leaves tracing off afterwards."""
+    import jax
+
+    from repro.engine import tracing
+
+    _run(monkeypatch, "--arch", "qwen3-1.7b-smoke", "--kv-backend", "paged",
+         "--num-relqueries", "2", "--max-requests", "2",
+         "--profile-dir", str(tmp_path))
+    assert "profile: trace with relserve.* spans" in capsys.readouterr().out
+    assert not tracing.enabled()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for plane in jax.profiler.ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events}
+    assert {"relserve.admit", "relserve.tick", "relserve.schedule",
+            "relserve.dispatch", "relserve.wait", "relserve.decode_sync",
+            "relserve.deliver"} <= names
