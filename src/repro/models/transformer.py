@@ -235,10 +235,10 @@ class DenseTransformer:
                     q, k, v = self._qkv(pp, p, h, positions, "global")
                 i = self.full_idx[p]
                 with scope("kv_write"):
-                    pools["k"] = pools["k"].at[g, i, bids, :, offs].set(
-                        k.astype(pools["k"].dtype))
-                    pools["v"] = pools["v"].at[g, i, bids, :, offs].set(
-                        v.astype(pools["v"].dtype))
+                    pools["k"] = self.write_token_kv(pools["k"], g, i, bids,
+                                                     offs, k)
+                    pools["v"] = self.write_token_kv(pools["v"], g, i, bids,
+                                                     offs, v)
                 with scope("attn"):
                     o = self._paged_attn(q, pools["k"][g, i], pools["v"][g, i],
                                          block_tables, context_lens,
@@ -252,6 +252,20 @@ class DenseTransformer:
         with scope("logits"):
             x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
             return self.logits(params, x), pools
+
+    @staticmethod
+    def write_token_kv(pool, g: int, i: int, block_ids, offsets, kv):
+        """Write each row's new-token K (or V) ``kv`` [B, KVs, hd] into layer
+        ``(g, i)`` of the stacked pool at slot ``offsets[b]`` of block
+        ``block_ids[b]``; returns the updated pool.
+
+        The KV-head dim is indexed too, so head_dim is the scatter's only
+        window and XLA updates the (donated) pool in place in its own layout.
+        Left as a window between index dims, the head dim makes XLA relayout
+        and copy the whole stacked pool at every layer."""
+        heads = jnp.arange(pool.shape[3])
+        return pool.at[g, i, block_ids[:, None], heads[None, :],
+                       offsets[:, None]].set(kv.astype(pool.dtype))
 
     @staticmethod
     def _paged_attn(q, k_pool, v_pool, block_tables, context_lens, positions,

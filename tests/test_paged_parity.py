@@ -13,6 +13,7 @@ import pytest
 from repro.engine.kv_cache import BlockManager
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.ref import paged_attention_ref
+from repro.models.transformer import DenseTransformer
 
 KEY = jax.random.PRNGKey(11)
 
@@ -203,3 +204,33 @@ def test_fork_conservation_under_churn():
     bm.free("c")
     bm.check_invariants()
     assert bm.free_blocks == bm.num_blocks
+
+
+@pytest.mark.parametrize("batch", [1, 5, 16])
+def test_decode_kv_write_matches_plain_indexing(batch):
+    """``write_token_kv`` leaves the stacked pool exactly as the plain
+    ``.at[g, i, bids, :, offs].set(kv)`` does, for random K/V, ragged positions
+    and fragmented block tables. Pad rows (the second half of the batch) all
+    write slot 0 of the scratch block, where which duplicate wins is
+    unspecified, so every block but that one is compared."""
+    G, NF, NB, KV, page, hd, per_seq = 3, 2, 41, 2, 4, 8, 2
+    scratch = NB - 1
+    live = max(1, batch // 2)
+    rng = np.random.RandomState(batch)
+    ks = jax.random.split(jax.random.PRNGKey(batch), 2)
+    pool = _rand((G, NF, NB, KV, page, hd), jnp.bfloat16, ks[0])
+    kv = _rand((batch, KV, hd), jnp.float32, ks[1])
+    tables = np.full((batch, per_seq), scratch, np.int32)
+    tables[:live] = rng.permutation(scratch)[: live * per_seq].reshape(
+        live, per_seq)
+    positions = np.zeros((batch,), np.int32)
+    positions[:live] = rng.randint(0, per_seq * page, size=live)
+    bids = jnp.asarray(tables[np.arange(batch), positions // page])
+    offs = jnp.asarray(positions % page)
+    for g, i in [(0, 0), (G - 1, NF - 1)]:
+        got = DenseTransformer.write_token_kv(pool, g, i, bids, offs, kv)
+        want = pool.at[g, i, bids, :, offs].set(kv.astype(pool.dtype))
+        np.testing.assert_array_equal(np.asarray(got[:, :, :scratch]),
+                                      np.asarray(want[:, :, :scratch]))
+        assert not np.array_equal(np.asarray(got[g, i, :scratch]),
+                                  np.asarray(pool[g, i, :scratch]))

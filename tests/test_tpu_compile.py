@@ -1,4 +1,5 @@
-"""The serving path's Pallas kernels compile for a TPU v5e at Qwen3-1.7B widths.
+"""The serving path's Pallas kernels and paged decode step compile for a TPU
+v5e at Qwen3-1.7B widths.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology, which catches what interpret mode cannot (block shapes Mosaic
@@ -7,6 +8,9 @@ import, so every xdist worker collects the same tests and only the worker
 that runs this file loads the TPU library. Skips where no TPU compiler is
 installed.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -15,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.paged_attention import paged_attention
+from repro.models.transformer import DenseTransformer
 
 CFG = get_config("qwen3-1.7b")
 KV = CFG.num_kv_heads
@@ -22,6 +27,9 @@ QP = CFG.num_heads // CFG.num_kv_heads
 HD = CFG.head_dim
 PAGE = 16          # build_real_engine's default block_size
 NUM_BLOCKS = 1041  # paged pool chip_smoke.py serves with, scratch block included
+# the pool build_real_engine derives under the default BatchLimits (the
+# benchmark's), scratch block included
+SERVED_BLOCKS = 1281
 
 
 @pytest.fixture(scope="module")
@@ -58,3 +66,35 @@ def test_flash_prefill_compiles_for_v5e(one_chip, length):
         _spec(one_chip, (1, KV, length, QP, HD)), kv, kv,
         interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_step_writes_kv_in_place_for_v5e(one_chip):
+    """The decode step's per-layer K/V write updates the donated stacked pool
+    in place: no instruction of the optimized HLO but a parameter or a bitcast
+    outputs the pool's full shape (a relayout of the pool shows up as
+    ``copy``/``copy_bitcast_fusion`` ops of that shape, one per layer). Four
+    layers keep the compile short; at two the pool is small enough to be
+    placed in on-chip memory and the HLO takes another form."""
+    model = DenseTransformer(dataclasses.replace(CFG, num_layers=4))
+    B, NB = 16, 8
+
+    def on_chip(x):
+        return _spec(one_chip, x.shape, x.dtype)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(model.init_params,
+                                                  jax.random.PRNGKey(0)))
+    pools = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: model.init_paged_pools(SERVED_BLOCKS, PAGE)))
+
+    def decode_step(params, pools, tokens, positions, tables, ctx):
+        return model.decode_step_paged(params, pools, tokens, positions,
+                                       tables, ctx, attn_impl="pallas")
+
+    rows = _spec(one_chip, (B,), jnp.int32)
+    hlo = jax.jit(decode_step, donate_argnums=(1,)).lower(
+        params, pools, rows, rows, _spec(one_chip, (B, NB), jnp.int32),
+        rows).compile().as_text()
+    pool_shape = "bf16[%s]" % ",".join(map(str, pools["k"].shape))
+    ops = re.findall(r"= %s\{[^}]*\} ([\w-]+)\(" % re.escape(pool_shape), hlo)
+    assert ops and "tpu_custom_call" in hlo
+    assert set(ops) <= {"parameter", "bitcast"}, sorted(set(ops))
