@@ -4,7 +4,7 @@ Once the window has closed and the program's state is freed, a sample of
 the rows of the relQueries due in the window that finished (the drain lets
 those with long answers finish), drawn from the seed and holding the row with
 the most served tokens and a row of the fullest decode batch, is run through
-the plain float32 reference, each prompt followed by the tokens the client
+the configuration's plain float32 reference, each prompt followed by the tokens the client
 received. For every served token the gap
 is how far its reference logit lies below the reference's best logit at
 that position; the widest gap is compared with the configuration's limit.
@@ -72,19 +72,21 @@ def compare(cfg: dict, params, rows, *, control: bool = False) -> Dict[str, floa
     """Readings of the sampled rows: ``widest_gap`` (of the served tokens;
     with ``control``, of the float8 reference's tokens in their place, and
     the served tokens' as ``program_widest_gap``), ``served_tokens`` and
-    ``stream_faults``."""
-    from reference import qwen
+    ``stream_faults``. The reference is the module the configuration names
+    (``cfg["reference"]``), for the control too."""
+    from harness import model
 
+    ref_mod = model.reference(cfg)
     seqs = [list(r.prompt) + list(r.tokens[:-1]) for r in rows]
     reads = [list(range(len(r.prompt) - 1, len(r.prompt) - 1 + len(r.tokens)))
              for r in rows]
-    ref = qwen.logits_at(cfg, params, seqs, reads)
+    ref = ref_mod.logits_at(cfg, params, seqs, reads)
     out = {"served_tokens": float(sum(len(r.tokens) for r in rows)),
            "stream_faults": float(stream_faults(rows))}
     gaps = [_gaps(lg, r.tokens) for lg, r in zip(ref, rows)]
     out["widest_gap"] = float(max(np.max(g) for g in gaps))
     if control:
-        ctl = qwen.logits_at(cfg, params, seqs, reads, quant="fp8")
+        ctl = ref_mod.logits_at(cfg, params, seqs, reads, quant="fp8")
         cg = [_gaps(lg, np.asarray(jnp.argmax(c, axis=-1)))
               for lg, c in zip(ref, ctl)]
         out["program_widest_gap"] = out["widest_gap"]

@@ -1,17 +1,23 @@
 """A configuration file, the program's model built from it, and its weights.
 
 A configuration (``bench/configs/<name>.json``) holds the published
-``config.json`` keys of the model as it is run here, plus ``serve``: the few
-facts the program's model needs that a dense HF config states through its
-``model_type`` (qk-norm, QKV bias). Weights are made on the device from the
-seed, in one jitted call, in the dtype they are served in, laid out as the
-program's single-device parameter tree. The plain reference reads the same
-arrays and reshapes them itself; nothing the program makes reaches it.
+``config.json`` keys of the model as it is run here, and names two modules
+of its own by their paths under ``bench/``: ``architecture`` (the program's
+``ModelConfig``, the parameter layout, the matmul parameters a token passes
+through and the layers that run attention; ``arch/qwen.py`` is one) and
+``reference`` (the plain float32 forward pass, ``logits_at``). Weights are
+made on the device from the seed, in one jitted call, in the dtype they are
+served in, laid out as the program's single-device parameter tree. The
+plain reference reads the same arrays and reshapes them itself; nothing the
+program makes reaches it.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 import math
+import re
+import sys
 from pathlib import Path
 
 import jax
@@ -27,58 +33,49 @@ BIAS_STD = 0.5
 
 
 def load_config(name: str, directory: Path = BENCH / "configs") -> dict:
-    cfg = json.loads((Path(directory) / f"{name}.json").read_text())
-    cfg.setdefault("head_dim", cfg["hidden_size"] // cfg["num_attention_heads"])
+    path = Path(directory) / f"{name}.json"
+    cfg = json.loads(path.read_text())
+    for key in ("architecture", "reference"):
+        if key not in cfg:
+            raise KeyError(f"{path} names no {key!r} module (a path under "
+                           f"{BENCH})")
+    if "num_attention_heads" in cfg:
+        cfg.setdefault("head_dim",
+                       cfg["hidden_size"] // cfg["num_attention_heads"])
     return cfg
 
 
-def program_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    serve = cfg["serve"]
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        attn_kind="full", qk_norm=serve["qk_norm"],
-        qkv_bias=serve["qkv_bias"], rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=cfg["tie_word_embeddings"],
-        dtype=cfg["torch_dtype"], source=cfg["source"])
+def load_module(rel: str):
+    """The module at ``bench/<rel>``, loaded once per process."""
+    path = (BENCH / rel).resolve()
+    name = "bench_" + re.sub(r"\W", "_", str(path.relative_to(BENCH.resolve())))
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
 
 
-def param_layout(cfg: dict) -> dict:
-    """name -> (shape, std) of every leaf, in the program's single-device
-    layout: layers stacked on axis 0 with a group axis of 1, query heads
-    packed as [kv_head, q_per_kv], query head h = kv * q_per_kv + j."""
-    L, D = cfg["num_hidden_layers"], cfg["hidden_size"]
-    H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd, F, V = cfg["head_dim"], cfg["intermediate_size"], cfg["vocab_size"]
-    Qp = H // KV
-    blocks = {
-        "ln1": ((L, 1, D), NORM_STD), "ln2": ((L, 1, D), NORM_STD),
-        "wq": ((L, 1, D, KV, Qp, hd), D ** -0.5),
-        "wk": ((L, 1, D, KV, hd), D ** -0.5),
-        "wv": ((L, 1, D, KV, hd), D ** -0.5),
-        "wo": ((L, 1, KV, Qp, hd, D), (H * hd) ** -0.5),
-        "w_gate": ((L, 1, D, F), D ** -0.5), "w_up": ((L, 1, D, F), D ** -0.5),
-        "w_down": ((L, 1, F, D), F ** -0.5),
-    }
-    if cfg["serve"]["qkv_bias"]:
-        blocks.update(bq=((L, 1, KV, Qp, hd), BIAS_STD),
-                      bk=((L, 1, KV, hd), BIAS_STD),
-                      bv=((L, 1, KV, hd), BIAS_STD))
-    if cfg["serve"]["qk_norm"]:
-        blocks.update(q_norm=((L, 1, hd), NORM_STD),
-                      k_norm=((L, 1, hd), NORM_STD))
-    out = {"embed": ((V, D), D ** -0.5), "final_norm": ((D,), NORM_STD),
-           "blocks": blocks}
-    if not cfg["tie_word_embeddings"]:
-        out["lm_head"] = ((D, V), D ** -0.5)
-    return out
+def architecture(cfg: dict):
+    """The configuration's architecture module: ``program_config``,
+    ``param_layout``, ``matmul_params_per_token``, ``attention_layers``."""
+    return load_module(cfg["architecture"])
+
+
+def reference(cfg: dict):
+    """The configuration's plain reference module (``logits_at``)."""
+    return load_module(cfg["reference"])
+
+
+def build(cfg: dict):
+    """The program's model for a configuration."""
+    from repro.models.registry import build_model
+    return build_model(architecture(cfg).program_config(cfg))
 
 
 def _uniform(key, shape, std, dtype):
@@ -87,29 +84,34 @@ def _uniform(key, shape, std, dtype):
 
 
 def make_params(cfg: dict, seed: int):
-    """All weights from ``seed`` in one jitted call on the default device.
-    Stacked layer leaves are drawn layer by layer (``lax.map``), so no
-    temporary larger than one layer's leaf is alive at once."""
+    """All weights from ``seed`` in one jitted call on the default device,
+    uniform draws of the architecture's ``param_layout``. A top-level entry
+    that is a dict is a group of stacked layer leaves, drawn layer by layer
+    (``lax.map``), so no temporary larger than one layer's leaf is alive at
+    once. Keys go to the groups' leaves (groups and leaves in name order),
+    then to the top-level leaves in name order."""
     dtype = jnp.dtype(cfg["torch_dtype"])
-    lay = param_layout(cfg)
+    lay = architecture(cfg).param_layout(cfg)
+    groups = sorted(g for g in lay if isinstance(lay[g], dict))
+    leaves = sorted(n for n in lay if n not in groups)
 
-    def build(lo, hi):
+    def draw(lo, hi):
         key = jax.random.fold_in(jax.random.key(lo), hi)
-        names = sorted(lay["blocks"]) + sorted(k for k in lay if k != "blocks")
+        names = [(g, n) for g in groups for n in sorted(lay[g])] + \
+            [(None, n) for n in leaves]
         keys = dict(zip(names, jax.random.split(key, len(names))))
-        blocks = {}
-        for n, (shape, std) in lay["blocks"].items():
-            layer_keys = jax.random.split(keys[n], shape[0])
-            blocks[n] = jax.lax.map(
-                lambda k, s=shape[1:], sd=std: _uniform(k, s, sd, dtype),
-                layer_keys)
-        out = {n: _uniform(keys[n], *lay[n], dtype)
-               for n in lay if n != "blocks"}
-        out["blocks"] = blocks
+        out = {n: _uniform(keys[None, n], *lay[n], dtype) for n in leaves}
+        for g in groups:
+            out[g] = {}
+            for n, (shape, std) in lay[g].items():
+                layer_keys = jax.random.split(keys[g, n], shape[0])
+                out[g][n] = jax.lax.map(
+                    lambda k, s=shape[1:], sd=std: _uniform(k, s, sd, dtype),
+                    layer_keys)
         return out
 
     lo, hi = seed & 0xFFFFFFFF, (seed >> 32) & 0xFFFFFFFF
-    return jax.jit(build)(jnp.uint32(lo), jnp.uint32(hi))
+    return jax.jit(draw)(jnp.uint32(lo), jnp.uint32(hi))
 
 
 def check_layout(params, model) -> None:
@@ -126,4 +128,4 @@ def param_count(cfg: dict) -> int:
     def n(tree):
         return sum(math.prod(v[0]) if isinstance(v, tuple) else n(v)
                    for v in tree.values())
-    return n(param_layout(cfg))
+    return n(architecture(cfg).param_layout(cfg))

@@ -11,25 +11,36 @@ in the instruction's ``op_name``, where all of the program's variants
 agree on it. ``reduce`` turns those lists into
 readings of the ``bench.window`` span, so it can be checked on a small
 synthetic trace. A program without the spans or scopes gives empty
-readings, not an error.
+readings, not an error. The scope names are the program's own
+(``repro.engine.tracing.SCOPES``) where it lists them.
 """
 from __future__ import annotations
 
 import bisect
-import glob
 import re
 import statistics
 from collections import defaultdict
-from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from harness.trace import CONTAINERS, OPS_LINE, WINDOW_SPAN, _clip, _union, op_kind
+from harness.trace import (CONTAINERS, OPS_LINE, WINDOW_SPAN, _clip, _union,
+                           host_ops, load, op_kind)
 
 PROGRAM_PREFIX = "relserve."
 HOST_PREFIXES = (PROGRAM_PREFIX, "bench.")
 MODULES_LINE = "XLA Modules"
-# the program's named scopes (models/transformer.py)
-SCOPES = ("embed", "qkv", "kv_write", "attn", "mlp", "logits")
+
+
+def _program_scopes() -> Tuple[str, ...]:
+    """The program's named scopes: its own list where it has one, else the
+    six of ``models/transformer.py``."""
+    try:
+        from repro.engine.tracing import SCOPES as scopes
+    except ImportError:
+        scopes = ("embed", "qkv", "kv_write", "attn", "mlp", "logits")
+    return tuple(scopes)
+
+
+SCOPES = _program_scopes()
 TICK = PROGRAM_PREFIX + "tick"
 AMBIGUOUS = "?"   # an instruction whose variants disagree on its scope
 SYNC_SPANS = (PROGRAM_PREFIX + "prefill_sync", PROGRAM_PREFIX + "decode_sync")
@@ -55,13 +66,7 @@ def scope_of(path: str) -> str:
 def extract(log_dir) -> dict:
     """{'spans': [Span], 'modules': {plane: [(program, start, dur)]},
     'ops': {plane: [Op]}} from the one trace under ``log_dir``."""
-    import jax
-
-    paths = glob.glob(str(Path(log_dir) / "**" / "*.xplane.pb"),
-                      recursive=True)
-    if len(paths) != 1:
-        raise RuntimeError(f"expected one trace under {log_dir}, found {paths}")
-    return from_profile(jax.profiler.ProfileData.from_file(paths[0]))
+    return from_profile(load(log_dir))
 
 
 def from_profile(pd) -> dict:
@@ -84,6 +89,8 @@ def from_profile(pd) -> dict:
             modules[plane.name] = mods
             if OPS_LINE in lines:
                 ops[plane.name] = _ops(lines[OPS_LINE].events, mods)
+    for plane, events in host_ops(pd).items():
+        ops[plane] = [(n, s, d, program_name(m)) for n, s, d, m in events]
     return {"spans": spans, "modules": modules, "ops": ops}
 
 
@@ -102,6 +109,21 @@ def _ops(events, mods) -> List[Op]:
     return out
 
 
+def hlo_texts(executor) -> Dict[str, List[str]]:
+    """Compiled HLO texts of every program the executor holds (its
+    attributes that are compiled executables, or dicts of them: one per
+    shape variant), keyed by the program's name as the trace gives it."""
+    out: Dict[str, List[str]] = defaultdict(list)
+    for value in vars(executor).values():
+        fns = value.values() if isinstance(value, dict) else [value]
+        for f in fns:
+            if hasattr(f, "as_text") and hasattr(f, "cost_analysis"):
+                text = f.as_text()
+                m = re.match(r"HloModule ([\w.\-]+)", text)
+                out[program_name(m.group(1) if m else "")].append(text)
+    return dict(out)
+
+
 def hlo_scopes(hlo_texts: Dict[str, Sequence[str]]
                ) -> Dict[Tuple[str, str], str]:
     """(program, instruction) -> the instruction's ``op_name``, from the
@@ -113,13 +135,34 @@ def hlo_scopes(hlo_texts: Dict[str, Sequence[str]]
     out = {}
     for program, texts in hlo_texts.items():
         for text in texts:
-            for m in re.finditer(r'%?([\w.\-]+) = [^\n]*?metadata=\{[^}]*?'
-                                 r'op_name="([^"]*)"', text):
-                key, path = (program, m.group(1)), m.group(2)
+            for name, path in op_names(text):
+                key = (program, name)
                 seen = out.setdefault(key, path)
                 if seen != AMBIGUOUS and scope_of(seen) != scope_of(path):
                     out[key] = AMBIGUOUS
     return out
+
+
+INSTRUCTION = re.compile(r"%?([\w.\-]+) = ")
+
+
+def op_names(text: str):
+    """(instruction, op_name) of each line of an HLO text whose metadata
+    has an ``op_name``. Line by line with plain string search: a 28-layer
+    decode step's text is some 3 MB, and a pattern over the whole text that
+    fails on most lines costs seconds for each."""
+    for line in text.splitlines():
+        meta = line.find("metadata={")
+        while meta >= 0:        # also ``kernel_metadata={}`` and the like
+            end = line.find("}", meta)
+            at = line.find('op_name="', meta, len(line) if end < 0 else end)
+            if at >= 0:
+                m = INSTRUCTION.search(line, 0, meta)
+                if m:
+                    at += len('op_name="')
+                    yield m.group(1), line[at:line.index('"', at)]
+                break
+            meta = line.find("metadata={", meta + 1)
 
 
 def instruction(name: str) -> str:
@@ -139,19 +182,23 @@ def reduce(ex: dict, scopes: Optional[Dict[Tuple[str, str], str]] = None,
     """Readings of the traced window, averaged over devices:
 
     - ``by_program``: device seconds and executions of each program;
-    - ``by_scope``: device seconds of each program/scope pair;
+    - ``by_scope``: device seconds of the ``top`` program/scope pairs;
+      ``scope_s``: of every pair, keyed by (program, scope);
     - ``owners``: for each of the ``top`` op kinds, the seconds of each
       program/scope that ran it;
     - ``idle_gaps``: the ``top`` longest idle gaps of the first device, each
       named by the innermost program span covering its midpoint;
     - ``host_ms_per_tick``: mean ms of the ticks inside the window, less
-      their host-blocked ``*_sync`` children.
+      their host-blocked ``*_sync`` children;
+    - ``spans``: the program's ``relserve.*`` spans inside the window, with
+      their arguments.
     """
     lo, hi = window_of(ex)
     scopes = scopes or {}
     n_dev = max(1, len(ex["ops"]))
     by_program: Dict[str, float] = defaultdict(float)
     by_scope: Dict[str, float] = defaultdict(float)
+    scope_s: Dict[Tuple[str, str], float] = defaultdict(float)
     by_kind: Dict[str, float] = defaultdict(float)
     owners: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
     for plane, events in ex["ops"].items():
@@ -166,6 +213,7 @@ def reduce(ex: dict, scopes: Optional[Dict[Tuple[str, str], str]] = None,
             where = f"{program or '?'}/{scope or '-'}"
             by_program[program or "?"] += sec
             by_scope[where] += sec
+            scope_s[program or "?", scope or "-"] += sec
             by_kind[kind] += sec
             owners[kind][where] += sec
     calls: Dict[str, int] = defaultdict(int)
@@ -179,6 +227,7 @@ def reduce(ex: dict, scopes: Optional[Dict[Tuple[str, str], str]] = None,
     return {
         "by_program": [[p, v, calls.get(p, 0) / n_dev] for p, v in
                        sorted(by_program.items(), key=lambda kv: -kv[1])],
+        "scope_s": dict(scope_s),
         "module_s": {p: v / n_dev for p, v in module_s.items()},
         "calls": {p: c / n_dev for p, c in calls.items()},
         "by_scope": [[k, v] for k, v in
@@ -187,6 +236,8 @@ def reduce(ex: dict, scopes: Optional[Dict[Tuple[str, str], str]] = None,
                              key=lambda wv: -wv[1])[:3] for k in top_kinds},
         "idle_gaps": idle_gaps(ex, lo, hi, top),
         "host_ms_per_tick": host_ms_per_tick(ex["spans"], lo, hi),
+        "spans": [sp for sp in ex["spans"] if sp[0].startswith(PROGRAM_PREFIX)
+                  and lo <= sp[1] and sp[1] + sp[2] <= hi],
     }
 
 

@@ -2,11 +2,12 @@
 
 The window runs ``Frontend`` over ``build_real_engine(..., "relserve",
 "paged")`` with the system's own defaults (``BatchLimits()``, the pool size
-the factory derives from the KV cap, the serial engine loop, ``max_len``
-512). The client here owns a wall clock: it submits each relQuery when it
-falls due, steps the engine, and stamps every streamed token on arrival
-(``on_token``). The engine's own clock is virtual (it advances by executor
-durations) and is read only to report how far it drifts from the wall.
+the factory derives from the KV cap, the serial engine loop), and the
+traffic's ``max_len`` (``MAX_LEN``, 512, where it states none). The client
+here owns a wall clock: it submits each relQuery when it falls due, steps
+the engine, and stamps every streamed token on arrival (``on_token``). The
+engine's own clock is virtual (it advances by executor durations) and is
+read only to report how far it drifts from the wall.
 
 Set-up warms every program shape the window can reach through the
 executor's public batch interface, on requests of its own that never reach
@@ -72,10 +73,15 @@ def _blocks(n: int) -> int:
     return -(-n // BLOCK)
 
 
-def build_engine(cfg_name: str, model, params):
+def max_len(mix: dict) -> int:
+    """A sequence's KV capacity: the traffic's ``max_len``, else 512."""
+    return int(mix.get("max_len", MAX_LEN))
+
+
+def build_engine(cfg_name: str, model, params, max_len: int = MAX_LEN):
     from repro.serving import build_real_engine
     return build_real_engine(cfg_name, "relserve", "paged", model=model,
-                             params=params, max_len=MAX_LEN)
+                             params=params, max_len=max_len)
 
 
 def warm_up(engine, queries) -> dict:
@@ -90,6 +96,7 @@ def warm_up(engine, queries) -> dict:
 
     ex = engine.core.executor
     limits = engine.core.scheduler.limits
+    cap = ex.max_len
     prompts = [p for q in queries for p in q.prompts]
     outs = [q.max_output_tokens for q in queries for _ in q.prompts]
     min_fp = min(len(p) + o for p, o in zip(prompts, outs))
@@ -143,11 +150,11 @@ def warm_up(engine, queries) -> dict:
     shortest = min(prompts, key=len)
     w_lo = _blocks(len(shortest) + 1)
     w_hi = _blocks(max(len(p) + o - 1 for p, o in zip(prompts, outs)))
-    classes = sorted({min(_pow2(w), _blocks(MAX_LEN))
+    classes = sorted({min(_pow2(w), _blocks(cap))
                       for w in range(w_lo, w_hi + 1)})
     base = max(by_class.values(), key=len)
     for wc in classes:
-        n_long = max(1, min(MAX_LEN, wc * BLOCK) - 12)
+        n_long = max(1, min(cap, wc * BLOCK) - 12)
         long_tokens = (list(base) * (n_long // len(base) + 1))[:n_long]
         k_max = min(rows_cap, 1 + (pool - wc) // _blocks(len(shortest) + 12))
         live = prefill_live(long_tokens, 1) + prefill_live(shortest, k_max - 1)
@@ -169,7 +176,9 @@ def run_window(engine, queries, seconds: float, *, preroll_s: float = 0.0,
     for up to ``drain_s`` after the close until every row of the relQueries
     due in the window has finished. ``on_open`` and ``on_close`` run when
     the window opens and closes. Ticks, step samples and scheduler counters
-    are those of the window; compiles are counted to the stop."""
+    are those of the window; compiles are counted to the stop. The window's
+    counters also hold the window's change in every numeric entry of the
+    executor's ``counters()``, where the program defines it."""
     from repro.core.relquery import RequestState, make_relquery
     from repro.serving import Frontend
 
@@ -221,7 +230,14 @@ def run_window(engine, queries, seconds: float, *, preroll_s: float = 0.0,
         return {"compile_count": ex.compile_count,
                 "schedule_s": core.schedule_time + core.schedule_retry_time,
                 "ticks": core.iterations, "pre": len(ex.prefill_samples),
-                "dec": len(ex.decode_samples)}
+                "dec": len(ex.decode_samples), "program": program_counters()}
+
+    def program_counters():
+        own = getattr(ex, "counters", None)
+        if not callable(own):
+            return {}
+        return {k: v for k, v in own().items()
+                if isinstance(v, (int, float)) and not isinstance(v, bool)}
 
     submitted = {}
     t0 = now() + preroll_s
@@ -266,6 +282,8 @@ def run_window(engine, queries, seconds: float, *, preroll_s: float = 0.0,
         "schedule_s": at_close["schedule_s"] - at_open["schedule_s"],
         "ticks": at_close["ticks"] - at_open["ticks"],
     }
+    for k, v in at_close["program"].items():
+        window_counters.setdefault(k, v - at_open["program"].get(k, 0))
     virtual = {rq.rel_id: rq.latency() for rq in rqs
                if rq.latency() is not None}
     for row in rows.values():

@@ -5,7 +5,9 @@ Two stages, so the second can be checked on a small recorded trace:
 lists (device ops of each device's "XLA Ops" line, and the benchmark's own
 ``bench.*`` host spans), and ``reduce`` turns those lists into numbers.
 Both clocks of one trace share an origin, so a device gap can be named by
-the host span it falls in.
+the host span it falls in. A trace with no device plane (the CPU's) holds
+its ops on the host's threads, as events that carry an ``hlo_op``; those
+stand in for the device's ops there.
 """
 from __future__ import annotations
 
@@ -22,16 +24,44 @@ WINDOW_SPAN = "bench.window"
 Event = Tuple[str, float, float]          # (name, start_ns, duration_ns)
 
 
-def extract(log_dir) -> dict:
-    """{'devices': {plane: [Event]}, 'host': [Event]} from the one trace
-    under ``log_dir``."""
+def load(log_dir):
+    """JAX's ``ProfileData`` of the one trace under ``log_dir``."""
     import jax
 
     paths = glob.glob(str(Path(log_dir) / "**" / "*.xplane.pb"),
                       recursive=True)
     if len(paths) != 1:
         raise RuntimeError(f"expected one trace under {log_dir}, found {paths}")
-    pd = jax.profiler.ProfileData.from_file(paths[0])
+    return jax.profiler.ProfileData.from_file(paths[0])
+
+
+def extract(log_dir) -> dict:
+    """{'devices': {plane: [Event]}, 'host': [Event]} from the one trace
+    under ``log_dir``."""
+    return from_profile(load(log_dir))
+
+
+def host_ops(pd) -> Dict[str, list]:
+    """{'/host:CPU:<ordinal>': [(name, start_ns, duration_ns, hlo_module)]}:
+    the XLA ops that ran on the host's threads, where the trace has no
+    device plane; empty where it has one."""
+    if any(plane.name.startswith("/device:") for plane in pd.planes):
+        return {}
+    out: Dict[str, list] = {}
+    for plane in pd.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    st = dict(e.stats)
+                    if "hlo_op" in st:
+                        key = f"/host:CPU:{st.get('device_ordinal', 0)}"
+                        out.setdefault(key, []).append(
+                            (e.name, e.start_ns, e.duration_ns,
+                             st.get("hlo_module", "")))
+    return out
+
+
+def from_profile(pd) -> dict:
     devices: Dict[str, List[Event]] = {}
     host: List[Event] = []
     for plane in pd.planes:
@@ -45,6 +75,8 @@ def extract(log_dir) -> dict:
                 host += [(e.name, e.start_ns, e.duration_ns)
                          for e in line.events
                          if e.name.startswith(SPAN_PREFIX)]
+    for plane, ops in host_ops(pd).items():
+        devices[plane] = [op[:3] for op in ops]
     return {"devices": devices, "host": host}
 
 
@@ -80,9 +112,10 @@ def op_kind(name: str) -> str:
 def reduce(ex: dict, kernels: Dict[str, Sequence[str]], top: int = 10) -> dict:
     """Numbers of the traced window (the ``bench.window`` span): busy
     seconds averaged over devices, seconds of each kernel (ops whose kind is
-    one of its names), the ``top`` device op kinds by time, and the
-    ``top`` longest idle gaps of the first device, each named by the
-    innermost benchmark span that covers the gap's midpoint."""
+    one of its names), seconds of every op kind (``op_s``; containers left
+    out), the ``top`` of those kinds by time, and the ``top`` longest idle
+    gaps of the first device, each named by the innermost benchmark span
+    that covers the gap's midpoint."""
     win = [e for e in ex["host"] if e[0] == WINDOW_SPAN]
     if len(win) != 1:
         raise RuntimeError(f"expected one {WINDOW_SPAN} span, found {len(win)}")
@@ -120,6 +153,7 @@ def reduce(ex: dict, kernels: Dict[str, Sequence[str]], top: int = 10) -> dict:
         "window_s": (hi - lo) / 1e9,
         "busy_s": sum(busy) / n_dev,
         "kernel_s": {k: v / n_dev for k, v in kernel_s.items()},
+        "op_s": {k: v / n_dev for k, v in by_kind.items()},
         "device_ops": [[k, v / n_dev] for k, v in
                        sorted(by_kind.items(), key=lambda kv: -kv[1])[:top]],
         "idle_gaps": named,

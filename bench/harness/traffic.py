@@ -20,8 +20,11 @@ golden-ratio sequence, so that large and small relQueries and short and long
 gaps alternate), from ``preroll_s`` before the window opens, so that the
 window opens on the queue that the rate sustains. A backlog is one deck, all
 due when the window opens. With a handful of relQueries in a window, a
-shuffled order would change the work from seed to seed; the seed draws the
-table text, and with it every prompt.
+shuffled order would change the work from seed to seed. The table's shape
+(the word count of each catalog item and review, which item each row shows,
+and which rows each relQuery takes) comes from the dataset alone, so every
+seed sends prompts of the same lengths with the same shared prefixes; the
+seed draws the words, and with them every token.
 """
 from __future__ import annotations
 
@@ -91,20 +94,29 @@ def _sentence(rng: random.Random, n: int) -> str:
     return " ".join(rng.choice(_WORDS) for _ in range(n))
 
 
+def shape_rng(dataset: str, part: str) -> random.Random:
+    """A generator of the table's shape: the dataset's alone."""
+    return random.Random(f"{dataset}/{part}")
+
+
 def make_table(dataset: str, seed: int) -> List[Dict[str, str]]:
     """Rows that reference a small catalog of shared item descriptions (value
-    overlap) and carry unique review text, sized to Table 4's prompt means."""
+    overlap) and carry unique review text, sized to Table 4's prompt means.
+    The word counts and each row's item are the same for every seed; the
+    seed draws the words."""
     avg_in, _ = DATASET_STATS[dataset]
+    shape = shape_rng(dataset, "table")
     rng = random.Random(seed ^ zlib.crc32(dataset.encode()))
     item_words = max(8, int(avg_in * 0.42))
     review_words = max(8, avg_in - item_words - 25)
-    catalog = [_sentence(rng, max(4, int(rng.gauss(item_words,
-                                                   item_words * 0.25))))
-               for _ in range(ITEMS_PER_CATALOG)]
-    return [{"item": rng.choice(catalog),
-             "review": _sentence(rng, max(4, int(rng.gauss(
-                 review_words, review_words * 0.3))))}
-            for _ in range(TABLE_ROWS)]
+    item_lens = [max(4, int(shape.gauss(item_words, item_words * 0.25)))
+                 for _ in range(ITEMS_PER_CATALOG)]
+    row_shapes = [(shape.randrange(ITEMS_PER_CATALOG),
+                   max(4, int(shape.gauss(review_words, review_words * 0.3))))
+                  for _ in range(TABLE_ROWS)]
+    catalog = [_sentence(rng, n) for n in item_lens]
+    return [{"item": catalog[item], "review": _sentence(rng, n)}
+            for item, n in row_shapes]
 
 
 @dataclass
@@ -204,10 +216,11 @@ def generate(mix: dict, seed: int, until: float,
             t += gap[j % n]
             j += 1
     table = make_table(mix["dataset"], rng.randrange(2 ** 32))
+    place = shape_rng(mix["dataset"], "offsets")
     tok = HashTokenizer(vocab_size=vocab_size - 2)
     out = []
     for i, (due, (rows, qtype)) in enumerate(slots):
-        offset = rng.randrange(0, len(table) - rows)
+        offset = place.randrange(0, len(table) - rows)
         prompts = [tok.encode(template_text(qtype, row["item"], row["review"]))
                    for row in table[offset:offset + rows]]
         out.append(Query(f"q{i}", qtype, due, OUTPUT_LIMITS[qtype], prompts))

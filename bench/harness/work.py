@@ -13,6 +13,8 @@ from typing import Iterable, Tuple
 
 import numpy as np
 
+from harness import model
+
 # op kinds (trace names without their instance suffix) of each kernel
 KERNEL_OPS = {"paged_attention": ("paged_attention",),
               "flash_prefill": ("flash_prefill",)}
@@ -47,26 +49,27 @@ def prefill_attention(cfg: dict, lengths: Iterable[int]) -> Tuple[float, float]:
     return flops, nbytes
 
 
-def layer_matmul_params(cfg: dict) -> int:
-    D, F = cfg["hidden_size"], cfg["intermediate_size"]
-    H, KV, hd, _ = _dims(cfg)
-    return D * H * hd + 2 * D * KV * hd + H * hd * D + 3 * D * F
+def attention_layers(cfg: dict) -> int:
+    """The layers that run attention, by the configuration's architecture."""
+    return model.architecture(cfg).attention_layers(cfg)
 
 
 def model_flops(cfg: dict, prefill_lengths: Iterable[int],
                 decode_contexts: Iterable[int]) -> float:
-    """Model FLOPs of whole prompts and of decoded tokens: the layers'
-    matmuls for every token, causal attention, and one row of output logits
-    per prompt and per decoded token."""
-    L, D, V = (cfg["num_hidden_layers"], cfg["hidden_size"],
-               cfg["vocab_size"])
-    pm = layer_matmul_params(cfg)
+    """Model FLOPs of whole prompts and of decoded tokens: the matmuls every
+    token passes through (active experts only, by the architecture), causal
+    attention on the layers that run it, and one row of output logits per
+    prompt and per decoded token."""
+    D, V = cfg["hidden_size"], cfg["vocab_size"]
+    arch = model.architecture(cfg)
     n = np.asarray(list(prefill_lengths), np.float64)
     c = np.asarray(list(decode_contexts), np.float64)
-    fa_p, _ = prefill_attention(cfg, n)
-    fa_d, _ = decode_attention(cfg, c)
-    return float(L * (2.0 * pm * (n.sum() + c.size) + fa_p + fa_d)
-                 + 2.0 * D * V * (n.size + c.size))
+    flops = 2.0 * arch.matmul_params_per_token(cfg) * (n.sum() + c.size)
+    layers = arch.attention_layers(cfg)
+    if layers:          # an attention-free model has no head sizes
+        flops += layers * (prefill_attention(cfg, n)[0] +
+                           decode_attention(cfg, c)[0])
+    return float(flops + 2.0 * D * V * (n.size + c.size))
 
 
 def least_time(flops: float, nbytes: float, peaks: dict) -> float:

@@ -40,7 +40,6 @@ def main(argv=None) -> int:
     import jax
 
     from harness import model as hmodel, serve, traffic
-    from repro.models.registry import build_model
 
     run.use_compile_cache()
     cfg = hmodel.load_config(args.config)
@@ -52,8 +51,8 @@ def main(argv=None) -> int:
     for q in queries:
         q.due = 0.0
     params = jax.block_until_ready(hmodel.make_params(cfg, args.seed))
-    engine = serve.build_engine(cfg["name"], build_model(
-        hmodel.program_config(cfg)), params)
+    engine = serve.build_engine(cfg["name"], hmodel.build(cfg), params,
+                                max_len=serve.max_len(mix))
     serve.warm_up(engine, queries)
     ex = engine.core.executor
     n_dec = len(ex.decode_samples)

@@ -81,10 +81,18 @@ def rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-def _layer(cfg, quant, x, lens, blocks, l):
-    """One decoder layer over rows x [R, T, D] (float32)."""
-    take = lambda a: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)[0]
-    w = {n: take(a) for n, a in blocks.items()}
+def layer_weights(blocks, l):
+    """Layer ``l``'s leaves of the stacked blocks (axis 0, then the group
+    axis of 1)."""
+    return {n: jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)[0]
+            for n, a in blocks.items()}
+
+
+def attention(cfg, quant, x, lens, w):
+    """One layer's attention block over rows x [R, T, D] (float32), its
+    residual added: pre-norm, q/k/v (bias, qk-norm where the weights have
+    them), rotary embeddings, causal softmax attention over each row's own
+    length, output projection."""
     R, T, D = x.shape
     H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     hd, eps = cfg["head_dim"], float(cfg["rms_norm_eps"])
@@ -112,17 +120,26 @@ def _layer(cfg, quant, x, lens, blocks, l):
     s = jnp.where(keep, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("rhqk,rkhd->rqhd", p, v, precision=HIGHEST)
-    x = x + linear(o.reshape(R, T, H * hd), w["wo"].reshape(H * hd, D), quant)
-
-    h = rmsnorm(x, w["ln2"], eps)
-    g = linear(h, w["w_gate"], quant)
-    u = linear(h, w["w_up"], quant)
-    return x + linear(jax.nn.silu(g) * u, w["w_down"], quant)
+    return x + linear(o.reshape(R, T, H * hd), w["wo"].reshape(H * hd, D), quant)
 
 
-@partial(jax.jit, static_argnames=("cfg_items", "quant"))
-def _layer_jit(x, lens, blocks, l, *, cfg_items, quant):
-    return _layer(dict(cfg_items), quant, x, lens, blocks, l)
+def swiglu(h, w_gate, w_up, w_down, quant):
+    """SwiGLU MLP of h [..., D] (float32) with weights stored [in, out]."""
+    g = linear(h, w_gate, quant)
+    u = linear(h, w_up, quant)
+    return linear(jax.nn.silu(g) * u, w_down, quant)
+
+
+def decoder_layer(cfg, quant, x, lens, w):
+    """One dense Qwen decoder layer: attention, then the SwiGLU MLP."""
+    x = attention(cfg, quant, x, lens, w)
+    h = rmsnorm(x, w["ln2"], float(cfg["rms_norm_eps"]))
+    return x + swiglu(h, w["w_gate"], w["w_up"], w["w_down"], quant)
+
+
+@partial(jax.jit, static_argnames=("cfg_items", "quant", "layer"))
+def _layer_jit(x, lens, blocks, l, *, cfg_items, quant, layer):
+    return layer(dict(cfg_items), quant, x, lens, layer_weights(blocks, l))
 
 
 @partial(jax.jit, static_argnames=("cfg_items", "quant"))
@@ -141,17 +158,21 @@ def _head_jit(x, rows, cols, params_head, *, cfg_items, quant):
 
 
 def _cfg_items(cfg: dict) -> Tuple:
-    keys = ("num_attention_heads", "num_key_value_heads", "head_dim",
-            "rms_norm_eps", "rope_theta")
-    return tuple((k, cfg[k]) for k in keys)
+    """The configuration's scalar keys, as a static argument of the jitted
+    steps."""
+    return tuple(sorted((k, v) for k, v in cfg.items()
+                        if v is None or isinstance(v, (bool, int, float, str))))
 
 
 def logits_at(cfg: dict, params, seqs: Sequence[Sequence[int]],
               reads: Sequence[Sequence[int]], *, quant: Optional[str] = None,
-              tokens_per_block: int = 4096) -> List[jax.Array]:
+              tokens_per_block: int = 4096, layer=decoder_layer
+              ) -> List[jax.Array]:
     """Logits [len(reads[i]), vocab] (float32, on the device) at positions
     ``reads[i]`` of each token sequence ``seqs[i]``. Rows run in blocks of
-    about ``tokens_per_block`` padded tokens, layer by layer."""
+    about ``tokens_per_block`` padded tokens, layer by layer; ``layer(cfg,
+    quant, x, lens, w)`` is one layer over rows x with that layer's leaves
+    w (another architecture's reference passes its own)."""
     items = _cfg_items(cfg)
     T = -(-max(len(s) for s in seqs) // 64) * 64
     per = max(1, tokens_per_block // T)
@@ -171,7 +192,7 @@ def logits_at(cfg: dict, params, seqs: Sequence[Sequence[int]],
         lens_d = jnp.asarray(lens)
         for l in range(cfg["num_hidden_layers"]):
             x = _layer_jit(x, lens_d, blocks, jnp.int32(l), cfg_items=items,
-                           quant=quant)
+                           quant=quant, layer=layer)
         rows = np.concatenate([np.full(len(reads[i]), j, np.int32)
                                for j, i in enumerate(idx)])
         cols = np.concatenate([np.asarray(reads[i], np.int32) for i in idx])

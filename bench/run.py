@@ -8,9 +8,12 @@ makes the weights on the device from the seed, builds the served path and
 warms every program shape the cell's traffic can reach; then the window
 serves the cell's traffic open loop for ``--seconds`` on the host clock.
 After the window the program's state is freed and a sample of what it served
-is compared with the plain float32 reference. ``--trace 1`` runs the same
-window under the profiler and reports the cell's per-layer metrics instead
-of its end-to-end ones.
+is compared with the configuration's plain float32 reference. ``--trace 1``
+runs the same window under the profiler and reports the cell's per-layer
+metrics instead of its end-to-end ones. Their readers get the trace's device
+time (``ctx.trace``) and the program's side of it (``ctx.program``: device
+time by step program and scope, the program's spans, host time per tick);
+the program's own spans are on only where a reader asks for them.
 
 Earlier lines of standard output carry readings for people; the last line
 is one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
@@ -71,9 +74,10 @@ def cell_spec(bench: dict, workload: str) -> dict:
     return {"cell": cell, "end_to_end": e2e, "per_layer": layer}
 
 
-def load_reader(name: str, directory: Path):
+def reader_module(name: str, directory: Path):
     """``bench/metrics/<name>.py``, else the file of its base name (the part
-    before the first dot), whose ``read(ctx)`` gives the value or None."""
+    before the first dot): its ``read(ctx)`` gives the value or None, and
+    ``SPANS = True`` asks for the program's spans in a traced run."""
     for stem in (name, name.split(".")[0]):
         path = directory / f"{stem}.py"
         if path.exists():
@@ -81,8 +85,12 @@ def load_reader(name: str, directory: Path):
                 f"bench_metric_{stem.replace('.', '_')}", path)
             mod = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(mod)
-            return mod.read
+            return mod
     raise FileNotFoundError(f"no reader for metric {name!r} in {directory}")
+
+
+def load_reader(name: str, directory: Path):
+    return reader_module(name, directory).read
 
 
 def use_compile_cache() -> str:
@@ -135,6 +143,19 @@ def instrument(engine, span) -> None:
     wrap(engine.core.executor, "wait", "bench.wait")
 
 
+def program_spans(on: bool):
+    """Turn the program's ``relserve.*`` spans on, where ``on`` and the
+    program has them; returns the function that turns them off again."""
+    if on:
+        try:
+            from repro.engine import tracing
+        except ImportError:
+            return lambda: None
+        tracing.enable()
+        return tracing.disable
+    return lambda: None
+
+
 def trace_hooks(log_dir: str, span) -> dict:
     """``on_open`` starts the profiler and then the ``bench.window`` span;
     ``on_close`` ends both. The span is made after the profiler starts: an
@@ -174,16 +195,18 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
     import jax
 
-    from harness import check, client, model as hmodel, serve, traffic
-    from harness import trace as htrace
+    from harness import check, client, model as hmodel, program_trace, serve
+    from harness import trace as htrace, traffic
     from harness.peaks import peaks_for
     from harness.work import KERNEL_OPS
-    from repro.models.registry import build_model
 
     dev = devs[0]
     peaks = peaks_for(dev.device_kind) if dev.platform == "tpu" else None
     say("device", dev.device_kind, "x", len(devs), "| compile cache",
         use_compile_cache() if compile_cache else "off")
+    wanted = spec["per_layer"] if trace else spec["end_to_end"]
+    readers = {m["name"]: reader_module(m["name"], metrics_dir)
+               for m in wanted}
     cfg = hmodel.load_config(cell["config"], config_dir)
     mix = traffic.load_mix(traffic_dir / f"{cell['traffic']}.json")
     preroll_s = float(mix.get("preroll_s", 0.0))
@@ -195,9 +218,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     params = jax.block_until_ready(hmodel.make_params(cfg, seed))
     say(f"weights {hmodel.param_count(cfg) / 1e9:.3f} B {cfg['torch_dtype']} "
         f"in {time.perf_counter() - t0:.2f} s")
-    model = build_model(hmodel.program_config(cfg))
+    model = hmodel.build(cfg)
     hmodel.check_layout(params, model)
-    engine = serve.build_engine(cfg["name"], model, params)
+    engine = serve.build_engine(cfg["name"], model, params,
+                                max_len=serve.max_len(mix))
     ex = engine.core.executor
     t0 = time.perf_counter()
     runs = serve.warm_up(engine, queries)
@@ -211,20 +235,33 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     if trace:
         instrument(engine, span)
         hooks = trace_hooks(log_dir, span)
-    window = serve.run_window(engine, queries, seconds, preroll_s=preroll_s,
-                              drain_s=drain_s, annotate=span, **hooks)
+    spans_off = program_spans(trace and any(
+        getattr(r, "SPANS", False) for r in readers.values()))
+    try:
+        window = serve.run_window(engine, queries, seconds,
+                                  preroll_s=preroll_s, drain_s=drain_s,
+                                  annotate=span, **hooks)
+    finally:
+        spans_off()
     # set-up ends when the window opens: the pre-roll is part of it
     setup_s = window.t0 - t_start
     stats = dev.memory_stats() or {}
     mem_peak = int(stats.get("peak_bytes_in_use", 0))
+    t_read = time.perf_counter()
+    texts = program_trace.hlo_texts(ex) if trace else None
     del engine, ex
     gc.collect()
 
-    reduced = None
+    reduced = program = None
     if trace:
         import shutil
-        reduced = htrace.reduce(htrace.extract(log_dir), KERNEL_OPS)
+        pd = htrace.load(log_dir)
+        reduced = htrace.reduce(htrace.from_profile(pd), KERNEL_OPS)
+        program = program_trace.reduce(program_trace.from_profile(pd),
+                                       program_trace.hlo_scopes(texts))
+        del pd, texts
         shutil.rmtree(log_dir, ignore_errors=True)
+        say(f"trace read in {time.perf_counter() - t_read:.1f} s")
     report_window(window, mem_peak)
 
     t_ref = time.perf_counter()
@@ -240,11 +277,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
     # what a metric reader may read
     ctx = SimpleNamespace(window=window, cfg=cfg, peaks=peaks, trace=reduced,
-                          setup_s=setup_s, client=client)
-    wanted = spec["per_layer"] if trace else spec["end_to_end"]
+                          program=program, setup_s=setup_s, client=client)
     metrics = {}
     for m in wanted:
-        value = load_reader(m["name"], metrics_dir)(ctx)
+        value = readers[m["name"]].read(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     device = {"platform": dev.platform, "kind": dev.device_kind,
@@ -257,6 +293,13 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         out["breakdown"] = {"device_ops": reduced["device_ops"],
                             "idle_gaps": reduced["idle_gaps"]}
         say("kernel seconds", json.dumps(reduced["kernel_s"]))
+        say("op seconds", json.dumps(reduced["op_s"]))
+        say("device seconds by program", json.dumps(program["by_program"]))
+        say("device seconds by program/scope",
+            json.dumps(sorted(([f"{p}/{sc}", v] for (p, sc), v in
+                               program["scope_s"].items()),
+                              key=lambda kv: -kv[1])))
+        say("host ms per tick", program["host_ms_per_tick"])
     out["checks"] = checks
     return out
 

@@ -90,6 +90,57 @@ def test_reduce_synthetic_program_trace():
         pytest.approx(0.001)
 
 
+def test_reduce_gives_every_scope_and_the_windows_spans():
+    """Every program/scope pair's seconds, not only the top ones, and the
+    program's spans that lie inside the window with their arguments."""
+    r = program_trace.reduce(synthetic(), SCOPES, top=1)
+    assert r["by_scope"] == [["decode_step/attn", pytest.approx(0.005)]]
+    assert r["scope_s"] == {("decode_step", "attn"): pytest.approx(0.005),
+                            ("decode_step", "mlp"): pytest.approx(0.001),
+                            ("prefill_step", "attn"): pytest.approx(0.001)}
+    ticks = [sp for sp in r["spans"] if sp[0] == "relserve.tick"]
+    assert [t[3] for t in ticks] == [{"tick": 0, "kind": "decode"},
+                                     {"tick": 1, "kind": "prefill"},
+                                     {"tick": 2}]     # the one at 11 ms is out
+    assert all(sp[0].startswith("relserve.") for sp in r["spans"])
+
+
+def test_scopes_are_the_programs_own(monkeypatch):
+    from repro.engine import tracing
+
+    monkeypatch.delattr(tracing, "SCOPES", raising=False)
+    assert program_trace._program_scopes() == (
+        "embed", "qkv", "kv_write", "attn", "mlp", "logits")
+    monkeypatch.setattr(tracing, "SCOPES", ("embed", "attn", "moe"),
+                        raising=False)
+    assert program_trace._program_scopes() == ("embed", "attn", "moe")
+
+
+def test_hlo_texts_takes_every_compiled_program():
+    """Every compiled executable the executor holds, alone or in a dict of
+    shape variants, keyed by the program name the trace gives it."""
+    import jax
+    import jax.numpy as jnp
+
+    def decode_step(x):
+        return x * 2
+
+    def copy_block(x):
+        return x + 1
+
+    compiled = lambda f, n: jax.jit(f).lower(jnp.ones((n,))).compile()
+    ex = SimpleNamespace(
+        _decode_fn={(1, 4): compiled(decode_step, 4),
+                    (2, 8): compiled(decode_step, 8)},
+        _copy_fn=compiled(copy_block, 4), _other=None, max_len=512,
+        decode_samples=[(1, 0.1)])
+    texts = program_trace.hlo_texts(ex)
+    assert sorted(texts) == ["copy_block", "decode_step"]
+    assert len(texts["decode_step"]) == 2
+    assert all(t.startswith("HloModule jit_decode_step")
+               for t in texts["decode_step"])
+
+
 def test_without_its_hlo_an_op_has_no_scope():
     r = program_trace.reduce(synthetic())
     assert r["by_scope"] == [["decode_step/-", pytest.approx(0.006)],
@@ -138,6 +189,21 @@ def test_hlo_scopes_reads_name_metadata():
     text = _hlo(("copy_bitcast_fusion.7", "jit(decode_step)/attn/squeeze"))
     assert program_trace.hlo_scopes({"decode_step": [text]}) == {
         ("decode_step", "copy_bitcast_fusion.7"): "jit(decode_step)/attn/squeeze"}
+
+
+def test_op_names_skip_other_metadata_blocks():
+    """An instruction's ``op_name`` is found past a block such as
+    ``kernel_metadata={}`` on its line; lines without one give nothing."""
+    text = ('  %fusion.1 = bf16[4]{0} fusion(%p.1), kind=kLoop, '
+            'frontend_attributes={kernel_metadata={}}, '
+            'metadata={op_name="jit(decode_step)/attn/pallas_call" id=9}\n'
+            '  ROOT %copy.2 = bf16[4]{0} copy(%fusion.1), '
+            'metadata={source_file="t.py" op_name="jit(decode_step)/mlp/dot"}\n'
+            '  %p.1 = bf16[4]{0} parameter(0)\n'
+            '  %c.3 = bf16[4]{0} copy(%p.1), metadata={source_file="t.py"}')
+    assert list(program_trace.op_names(text)) == [
+        ("fusion.1", "jit(decode_step)/attn/pallas_call"),
+        ("copy.2", "jit(decode_step)/mlp/dot")]
 
 
 def test_hlo_scopes_of_variants_that_disagree_are_ambiguous():

@@ -1,6 +1,8 @@
 """The float32 reference against the program's own forward pass, at a small
 size on the CPU, for both paths: Qwen3 (qk-norm, tied head) and Qwen2.5
-(QKV bias, untied head)."""
+(QKV bias, untied head). Both are reached as every run reaches them: the
+program's model through the configuration's architecture module, the
+reference through the module its ``reference`` key names."""
 from pathlib import Path
 
 import jax
@@ -9,19 +11,16 @@ import numpy as np
 import pytest
 
 from harness import model as hmodel
-from reference import qwen
 
 DATA = Path(__file__).resolve().parent / "data"
 
 
 @pytest.fixture(params=["tiny-qwen3", "tiny-qwen2.5"])
 def setup(request):
-    from repro.models.registry import build_model
-
     cfg = hmodel.load_config(request.param, DATA)
     cfg["torch_dtype"] = "float32"
     params = hmodel.make_params(cfg, 2 ** 35 + 17)
-    prog = build_model(hmodel.program_config(cfg))
+    prog = hmodel.build(cfg)
     hmodel.check_layout(params, prog)
     return cfg, params, prog
 
@@ -42,7 +41,8 @@ def test_reference_matches_the_program_in_float32(setup):
     lens = [37, 64, 5]
     seqs = [list(rng.randint(2, cfg["vocab_size"], n)) for n in lens]
     reads = [list(range(n)) for n in lens]
-    ref = qwen.logits_at(cfg, params, seqs, reads, tokens_per_block=128)
+    ref = hmodel.reference(cfg).logits_at(cfg, params, seqs, reads,
+                                          tokens_per_block=128)
     for s, r in zip(seqs, ref):
         want = program_logits(prog, params, np.asarray([s], np.int32))[0]
         np.testing.assert_allclose(np.asarray(r), want, rtol=2e-4, atol=2e-4)
@@ -54,11 +54,12 @@ def test_reference_sees_each_architectural_part(setup):
     cfg, params, prog = setup
     seqs = [[1, 5, 9, 13, 17, 21, 25, 29]]
     reads = [list(range(8))]
-    base = np.asarray(qwen.logits_at(cfg, params, seqs, reads)[0])
+    logits_at = hmodel.reference(cfg).logits_at
+    base = np.asarray(logits_at(cfg, params, seqs, reads)[0])
     name = "q_norm" if cfg["serve"]["qk_norm"] else "bq"
     broken = jax.tree.map(lambda a: a, params)
     broken["blocks"][name] = jnp.zeros_like(broken["blocks"][name]) + 0.7
-    moved = np.asarray(qwen.logits_at(cfg, broken, seqs, reads)[0])
+    moved = np.asarray(logits_at(cfg, broken, seqs, reads)[0])
     assert np.max(np.abs(moved - base)) > 1e-2
 
 
@@ -67,7 +68,8 @@ def test_fp8_control_departs_more_than_rounding(setup):
     rng = np.random.RandomState(1)
     seqs = [list(rng.randint(2, cfg["vocab_size"], 48))]
     reads = [list(range(48))]
-    ref = np.asarray(qwen.logits_at(cfg, params, seqs, reads)[0])
-    ctl = np.asarray(qwen.logits_at(cfg, params, seqs, reads, quant="fp8")[0])
+    logits_at = hmodel.reference(cfg).logits_at
+    ref = np.asarray(logits_at(cfg, params, seqs, reads)[0])
+    ctl = np.asarray(logits_at(cfg, params, seqs, reads, quant="fp8")[0])
     err = np.max(np.abs(ctl - ref)) / np.max(np.abs(ref))
     assert 1e-3 < err < 0.5

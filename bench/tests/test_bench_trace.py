@@ -51,6 +51,17 @@ def test_reduce_synthetic():
     assert gaps[2] == ["none", pytest.approx(0.001)]              # 1-2 ms
 
 
+def test_op_seconds_of_every_kind():
+    """``op_s`` holds every op kind's seconds, beyond the top ones, and
+    ``kernel_s`` reads the same seconds for the two kernels."""
+    r = trace.reduce(synthetic(), work.KERNEL_OPS, top=1)
+    assert r["device_ops"] == [["paged_attention", pytest.approx(0.004)]]
+    assert r["op_s"] == {"paged_attention": pytest.approx(0.004),
+                         "flash_prefill": pytest.approx(0.001),
+                         "fusion": pytest.approx(0.001)}
+    assert r["kernel_s"] == {k: r["op_s"][k] for k in work.KERNEL_OPS}
+
+
 @pytest.mark.parametrize("name,kind", [
     ("%copy_bitcast_fusion.54 = bf16[28,1281]{1,0} fusion(%p.1)",
      "copy_bitcast_fusion"),
@@ -101,9 +112,16 @@ def test_extract_a_cpu_trace(tmp_path):
     ex = trace.extract(tmp_path)
     names = {e[0] for e in ex["host"]}
     assert {"bench.window", "bench.dispatch"} <= names
-    assert ex["devices"] == {}          # the CPU has no device plane
-    with pytest.raises(RuntimeError):
-        trace.reduce(ex, work.KERNEL_OPS)
+    # the CPU has no device plane: its ops are the host threads' events
+    # that carry an ``hlo_op``, the program's own ops and nothing else
+    assert list(ex["devices"]) == ["/host:CPU:0"]
+    kinds = {trace.op_kind(e[0]) for e in ex["devices"]["/host:CPU:0"]}
+    assert "dot_general" in kinds
+    assert not any(k.startswith(("Threadpool", "PjRt", "end:")) for k in kinds)
+    r = trace.reduce(ex, work.KERNEL_OPS)
+    assert 0 < r["busy_s"] <= r["window_s"]
+    assert r["op_s"]["dot_general"] > 0
+    assert r["kernel_s"] == {"paged_attention": 0.0, "flash_prefill": 0.0}
 
 
 def ctx(ticks, kernel_s, window_s=1.0, busy_s=0.5):

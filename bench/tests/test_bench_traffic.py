@@ -29,6 +29,22 @@ def test_seeds_share_the_deck_in_one_order():
     assert [q.prompts for q in a] != [q.prompts for q in b]
 
 
+@pytest.mark.parametrize("mix", [MIX, SHORT], ids=["open", "backlog"])
+def test_seeds_send_prompts_of_the_same_lengths(mix):
+    """The seed draws the words alone: every seed sends the same prompt
+    lengths, row for row, and shares prefixes among the same rows."""
+    a, b = (traffic.generate(mix, s, 141, 151936) for s in (11, 2 ** 31 + 9))
+    lens = lambda qs: [[len(p) for p in q.prompts] for q in qs]
+    assert lens(a) == lens(b)
+
+    def shared(qs):
+        first = {}
+        return [first.setdefault(tuple(p[:24]), (i, j))
+                for i, q in enumerate(qs) for j, p in enumerate(q.prompts)]
+    assert shared(a) == shared(b)
+    assert [q.prompts for q in a] != [q.prompts for q in b]
+
+
 def test_spread_order_alternates_sizes():
     qs = traffic.generate(MIX, 1, 100, 151936)
     big = [len(q.prompts) > 50 for q in qs]

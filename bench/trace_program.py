@@ -64,15 +64,6 @@ def span_cost_ns(tracing, n: int = 20000) -> dict:
     return {"off_ns": off, "on_ns": on}
 
 
-def hlo_texts(ex) -> dict:
-    """Compiled HLO texts of each step program the executor holds, one per
-    shape variant."""
-    return {program: [f.as_text() for f in fns.values()]
-            for program, fns in (("prefill_step", ex._prefill_fn),
-                                 ("scatter_prefill", ex._scatter_fn),
-                                 ("decode_step", ex._decode_fn))}
-
-
 def trace_cell(workload: str, seed: int, seconds: float, *, bench: dict,
                config_dir: Path = run.BENCH / "configs",
                traffic_dir: Path = run.BENCH / "traffic",
@@ -91,7 +82,6 @@ def trace_cell(workload: str, seed: int, seconds: float, *, bench: dict,
     from harness.peaks import peaks_for
     from harness.work import KERNEL_OPS
     from repro.engine import tracing
-    from repro.models.registry import build_model
 
     dev = devs[0]
     peaks = peaks_for(dev.device_kind) if dev.platform == "tpu" else None
@@ -102,8 +92,8 @@ def trace_cell(workload: str, seed: int, seconds: float, *, bench: dict,
     drain_s = float(mix.get("drain_s", 0.0))
     queries = traffic.generate(mix, seed, seconds + drain_s, cfg["vocab_size"])
     params = jax.block_until_ready(hmodel.make_params(cfg, seed))
-    model = build_model(hmodel.program_config(cfg))
-    engine = serve.build_engine(cfg["name"], model, params)
+    engine = serve.build_engine(cfg["name"], hmodel.build(cfg), params,
+                                max_len=serve.max_len(mix))
     core, ex = engine.core, engine.core.executor
     serve.warm_up(engine, queries)
 
@@ -129,7 +119,7 @@ def trace_cell(workload: str, seed: int, seconds: float, *, bench: dict,
     cost = core.cost_samples[marks[0]:marks[1]]
 
     pex = program_trace.extract(log_dir)
-    scopes = program_trace.hlo_scopes(hlo_texts(ex))
+    scopes = program_trace.hlo_scopes(program_trace.hlo_texts(ex))
     utok = sum(n for n, _ in window.prefill_samples)
     if dump:
         ops = {p: [[program_trace.instruction(o[0]), *o[1:]] for o in v]
